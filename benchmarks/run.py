@@ -82,6 +82,8 @@ def main() -> None:
                          "roofline")
     ap.add_argument("--budget-edges", type=int, default=200_000)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # multihost and sample spawn their own 2-process fleets, so they are
     # opt-in (not part of the default sweep: nightly CI runs them
     # explicitly)

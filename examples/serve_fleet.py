@@ -2,19 +2,20 @@
 
     PYTHONPATH=src python examples/serve_fleet.py
 
-Forces ``--xla_force_host_platform_device_count=8`` (before jax import), so
-a laptop CPU behaves like an 8-device host: the FleetGraphEngine places
+Defaults ``--xla_force_host_platform_device_count=8`` (before jax import),
+so a CPU-only host behaves like an 8-device host; the flag touches only the
+CPU backend, so on a TPU host the fleet spans the real chips. The
+FleetGraphEngine places
 each registered graph's partition plan on one device (consistent-hash +
 load-aware override), groups every flush by owning device, and launches the
 per-device fused dispatches concurrently. A narrow giant graph takes the
 block-sharded whole-mesh path instead — its partition blocks round-robin
-across all 8 devices and the per-device row slabs psum back together.
+across all devices and the per-device row slabs psum back together.
 """
 import argparse
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax                                                    # noqa: E402
 import jax.numpy as jnp                                       # noqa: E402

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import pallas_interpret
+
 
 def _gmm_kernel(expert_ref, x_ref, w_ref, out_ref):
     """x_ref: [m_tile, k_tile]; w_ref: [1, k_tile, n_tile]; out: [m_tile, n_tile]."""
@@ -47,23 +49,7 @@ def _gmm_kernel(expert_ref, x_ref, w_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("m_tile", "k_tile", "n_tile", "interpret"))
-def grouped_matmul(
-    x: jax.Array,             # [M, K] rows sorted+padded by expert; M % m_tile == 0
-    w: jax.Array,             # [E, K, N]
-    block_expert: jax.Array,  # int32[M // m_tile] expert id per row block
-    *,
-    m_tile: int = 128,
-    k_tile: int = 512,
-    n_tile: int = 128,
-    interpret: bool = True,
-) -> jax.Array:
-    """Block-balanced grouped GEMM; returns [M, N] float32.
-
-    The w BlockSpec's index_map reads the scalar-prefetched ``block_expert``
-    metadata, so each grid step DMAs exactly one expert's (k_tile x n_tile)
-    weight tile — the same "all warps deduce their workload from one block
-    record" trick as the paper's int4 metadata.
-    """
+def _grouped_matmul(x, w, block_expert, *, m_tile, k_tile, n_tile, interpret):
     M, K = x.shape
     E, K2, N = w.shape
     assert K == K2 and M % m_tile == 0, (x.shape, w.shape, m_tile)
@@ -82,10 +68,29 @@ def grouped_matmul(
         ],
         out_specs=pl.BlockSpec((m_tile, n_tile), lambda b, j, k, e: (b, j)),
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _gmm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         interpret=interpret,
     )(block_expert, x, w)
-    return out
+
+
+def grouped_matmul(
+    x: jax.Array,             # [M, K] rows sorted+padded by expert; M % m_tile == 0
+    w: jax.Array,             # [E, K, N]
+    block_expert: jax.Array,  # int32[M // m_tile] expert id per row block
+    *,
+    m_tile: int = 128,
+    k_tile: int = 512,
+    n_tile: int = 128,
+) -> jax.Array:
+    """Block-balanced grouped GEMM; returns [M, N] float32.
+
+    The w BlockSpec's index_map reads the scalar-prefetched ``block_expert``
+    metadata, so each grid step DMAs exactly one expert's (k_tile x n_tile)
+    weight tile — the same "all warps deduce their workload from one block
+    record" trick as the paper's int4 metadata.
+    """
+    return _grouped_matmul(x, w, block_expert, m_tile=m_tile, k_tile=k_tile,
+                           n_tile=n_tile, interpret=pallas_interpret())

@@ -1,7 +1,8 @@
 """Jit'd wrappers around the Pallas kernels + portable jnp twins.
 
 Every kernel has three callables:
-  * ``*_pallas``  — the Pallas kernel (interpret=True on CPU, compiled on TPU)
+  * ``*_pallas``  — the Pallas kernel (interpreted on CPU, compiled on TPU;
+                    see ``platform.pallas_interpret``)
   * ``*_blocked`` — a pure-jnp twin with the *same* slab layout and math
                     (the portable production path; XLA fuses it well)
   * oracle        — in ref.py (layout-free ground truth)
@@ -14,7 +15,9 @@ import jax
 import jax.numpy as jnp
 
 from .router import route_spmm
-from .spmm_accel import spmm_block_slabs, spmm_block_slabs_windowed
+from .spmm_accel import (
+    scatter_block_rows, spmm_block_slabs, spmm_block_slabs_windowed,
+)
 from .spmm_hbm import spmm_block_slabs_hbm
 from .grouped_matmul import grouped_matmul
 
@@ -24,41 +27,40 @@ __all__ = ["spmm_pallas", "spmm_pallas_windowed", "spmm_pallas_hbm",
 
 
 def spmm_batched(slab_list, x_list, n_rows_list, *, backend="pallas",
-                 interpret=True, pad_blocks_to=None, return_decision=False):
+                 pad_blocks_to=None, return_decision=False):
     """Fused multi-graph SpMM (one pallas_call for the whole batch)."""
     from .spmm_batched import spmm_batched as _batched
     return _batched(slab_list, x_list, n_rows_list, backend=backend,
-                    interpret=interpret, pad_blocks_to=pad_blocks_to,
+                    pad_blocks_to=pad_blocks_to,
                     return_decision=return_decision)
 
 
-def spmm_pallas(slabs, x, n_rows, *, interpret=True):
+def spmm_pallas(slabs, x, n_rows):
     """Resident-X kernel; raises VmemBudgetError past N_pad <= 4096 (f32)."""
     return spmm_block_slabs(
         slabs["colidx"], slabs["values"], slabs["rowloc"], slabs["out_row"],
-        x, n_rows, interpret=interpret,
+        x, n_rows,
     )
 
 
-def spmm_pallas_windowed(slabs, x, n_rows, *, interpret=True,
-                         window_rows=None):
+def spmm_pallas_windowed(slabs, x, n_rows, *, window_rows=None):
     """Row-window streaming variant: X visits VMEM one window at a time."""
     return spmm_block_slabs_windowed(
         slabs["colidx"], slabs["values"], slabs["rowloc"], slabs["out_row"],
-        x, n_rows, interpret=interpret, window_rows=window_rows,
+        x, n_rows, window_rows=window_rows,
     )
 
 
-def spmm_pallas_hbm(slabs, x, n_rows, *, interpret=True):
-    """HBM-resident X variant (double-buffered DMA gather) for graphs whose
-    feature tile exceeds VMEM."""
+def spmm_pallas_hbm(slabs, x, n_rows):
+    """HBM-resident X variant (pipelined one-row DMA gather) for graphs
+    whose feature tile exceeds VMEM."""
     return spmm_block_slabs_hbm(
         slabs["colidx"], slabs["values"], slabs["rowloc"], slabs["out_row"],
-        x, n_rows, interpret=interpret,
+        x, n_rows,
     )
 
 
-def spmm_auto(slabs, x, n_rows, *, interpret=True, return_decision=False):
+def spmm_auto(slabs, x, n_rows, *, return_decision=False):
     """VMEM-routed single-graph dispatch: resident / windowed / hbm chosen
     from the feature-operand shape (see ``router.route_spmm``)."""
     decision = route_spmm(
@@ -67,7 +69,7 @@ def spmm_auto(slabs, x, n_rows, *, interpret=True, return_decision=False):
         itemsize=jnp.dtype(x.dtype).itemsize)
     fn = {"resident": spmm_pallas, "windowed": spmm_pallas_windowed,
           "hbm": spmm_pallas_hbm}[decision.backend]
-    out = fn(slabs, x, n_rows, interpret=interpret)
+    out = fn(slabs, x, n_rows)
     return (out, decision) if return_decision else out
 
 
@@ -93,17 +95,17 @@ def spmm_blocked(colidx, values, rowloc, out_row, x, n_rows, block_chunk: int = 
         ci_c, va_c, rl_c = args
         gathered = va_c[..., None].astype(jnp.float32) * x[ci_c].astype(jnp.float32)
         onehot = jax.nn.one_hot(rl_c, R, dtype=jnp.float32)
-        return jnp.einsum("bcr,bcf->brf", onehot, gathered)
+        # HIGHEST: the TPU's default f32 matmul rounds operands to bf16
+        return jnp.einsum("bcr,bcf->brf", onehot, gathered,
+                          precision=jax.lax.Precision.HIGHEST)
 
     slab_out = jax.lax.map(chunk_fn, (ci, va, rl))          # [nc, bc, R, F]
-    flat = slab_out.reshape(Bp * R, F)[: B * R]
-    seg = out_row.reshape(B * R)
-    out = jax.ops.segment_sum(flat, seg, num_segments=n_rows + 1)
-    return out[:n_rows]
+    return scatter_block_rows(slab_out.reshape(Bp, R, F),
+                              padded(out_row, n_rows), n_rows, F)
 
 
-def grouped_matmul_pallas(x, w, block_expert, *, interpret=True, **tiles):
-    return grouped_matmul(x, w, block_expert, interpret=interpret, **tiles)
+def grouped_matmul_pallas(x, w, block_expert, **tiles):
+    return grouped_matmul(x, w, block_expert, **tiles)
 
 
 @functools.partial(jax.jit, static_argnames=("m_tile",))

@@ -23,7 +23,8 @@ def csr_spmm_ref(rowptr: np.ndarray, colidx: np.ndarray, values: np.ndarray,
     if len(colidx) == 0:
         return jnp.zeros((n, x.shape[1]), dtype=jnp.promote_types(x.dtype, jnp.float32))
     contrib = values[:, None].astype(jnp.float32) * x[colidx].astype(jnp.float32)
-    out = jax.ops.segment_sum(contrib, jnp.asarray(row_of), num_segments=n)
+    out = jax.ops.segment_sum(contrib, jnp.asarray(row_of), num_segments=n,
+                              indices_are_sorted=True)   # CSR row order
     return out
 
 

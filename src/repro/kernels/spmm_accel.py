@@ -9,11 +9,20 @@ TPU mapping of the paper's design (DESIGN.md §2):
   grid axis — the *combined warp*: every HBM<->VMEM transfer of a dense row is
   a full-lane contiguous vector;
 * the intra-block segment reduction (the paper's shared-memory
-  ``atomicAdd_block``) becomes a one-hot MXU matmul ``[R, C] @ [C, F_tile]``
-  entirely in VMEM — no atomics exist or are needed;
+  ``atomicAdd_block``) becomes a weighted one-hot MXU matmul
+  ``[R, C] @ [C, F_tile]`` entirely in VMEM — no atomics exist or are needed;
 * cross-block accumulation for split rows (degree > C) is a segment-sum
   epilogue over the packed block outputs (TPU grids are sequential, so a
   revisit-accumulate output alias is also legal; see ops.py notes).
+
+Slab metadata enters each grid step as ``[1, 1, C]`` blocks of the
+``[B, 1, C]`` view of the ``[B, C]`` slabs (a ``(1, C)`` block of a
+``[B, C]`` array breaks the TPU's (8, 128) block-tiling rule). ``colidx``
+goes to SMEM, one block per step, and is read as scalars: the row gather is
+a loop of scalar-addressed row reads into a ``[C, F_tile]`` VMEM scratch (a
+vector-indexed gather ``x_ref[cols, :]`` does not lower on the TPU). The
+whole ``colidx`` array is never scalar-prefetched: at full size it is far
+larger than SMEM.
 
 VMEM budget per grid step (f32, defaults C=256, R=64, F_tile=128; the
 routing arithmetic lives in ``router.py``):
@@ -24,8 +33,9 @@ routing arithmetic lives in ``router.py``):
                                 N_pad<=4096: 2MiB  2 MiB x 2 bufs
   gathered slab [C, F_tile]     128 KiB           128 KiB
   out slab      [R, F_tile]     32 KiB (x2 bufs)  32 KiB (x2 bufs)
-  colidx/values/rowloc [C]      3 KiB  (x2 bufs)  3 KiB  (x2 bufs)
-  one-hot       [C, R]          64 KiB            64 KiB
+  values/rowloc [C] (+colidx    3 KiB  (x2 bufs)  3 KiB  (x2 bufs)
+  [C] in SMEM)
+  weighted one-hot [R, C]       64 KiB            64 KiB
 
 * ``spmm_block_slabs`` (resident): the whole X tile sits in VMEM. Guarded —
   N_pad over the 2 MiB tile budget raises ``VmemBudgetError`` at trace time
@@ -36,6 +46,9 @@ routing arithmetic lives in ``router.py``):
   revisit accumulation is legal). Middle regime: N_pad <= 4 windows.
 * beyond that, ``spmm_hbm.spmm_block_slabs_hbm`` gathers rows straight from
   HBM. ``router.route_spmm`` picks between the three automatically.
+
+Every entry point runs compiled on a TPU and interpreted on the CPU
+(:func:`repro.kernels.platform.pallas_interpret`).
 """
 from __future__ import annotations
 
@@ -44,7 +57,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from .platform import pallas_interpret
 from .router import (
     assert_resident_fits,
     pad_features,
@@ -54,6 +69,7 @@ from .router import (
 
 
 DEFAULT_F_TILE = 128  # lane width — the "combined warp" quantum on TPU
+SCATTER_CHUNK = 64    # most blocks folded by one scatter-add of the epilogue
 
 
 def scatter_block_rows(out_slabs: jax.Array, out_row: jax.Array,
@@ -62,46 +78,132 @@ def scatter_block_rows(out_slabs: jax.Array, out_row: jax.Array,
     block rows -> global [n_rows, n_features]. Non-split blocks write
     disjoint rows; split-row blocks accumulate; slot n_rows is the padding
     sentinel and is dropped (sequential-grid revisit accumulation is the
-    real-TPU alternative; see DESIGN.md §2)."""
+    real-TPU alternative; see DESIGN.md §2).
+
+    Blocks fold in order, ``chunk`` at a time (the largest power of two up
+    to ``SCATTER_CHUNK`` that divides B), by a loop of small scatter-adds:
+    one scatter over all B*R rows takes the TPU compiler tens of seconds at
+    full-graph sizes, the loop under one.
+    """
     B, R, F_pad = out_slabs.shape
-    flat = out_slabs.reshape(B * R, F_pad)
-    seg = out_row.reshape(B * R)
-    out = jax.ops.segment_sum(flat, seg, num_segments=n_rows + 1)
-    return out[:n_rows, :n_features]
+    acc = jnp.zeros((n_rows + 1, F_pad), out_slabs.dtype)
+    if B:
+        chunk = min(B & -B, SCATTER_CHUNK)
+        rows = out_slabs.reshape(B // chunk, chunk * R, F_pad)
+        seg = out_row.reshape(B // chunk, chunk * R)
+        # the first chunk seeds the carry, so under shard_map it carries
+        # the same device-varying type as every later step
+        acc = jax.lax.fori_loop(
+            1, B // chunk, lambda i, a: a.at[seg[i]].add(rows[i]),
+            acc.at[seg[0]].add(rows[0]))
+    return acc[:n_rows, :n_features]
 
 
-def _spmm_kernel(colidx_ref, values_ref, rowloc_ref, x_ref, out_ref, *, C, R):
+def slab_meta_specs(C: int, index_map):
+    """BlockSpecs of the ``[B, 1, C]`` colidx / values / rowloc views: one
+    block per grid step; colidx in SMEM (scalar row addresses), values and
+    rowloc in VMEM (vector operands of the weighted one-hot)."""
+    return [
+        pl.BlockSpec((1, 1, C), index_map, memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, 1, C), index_map),
+        pl.BlockSpec((1, 1, C), index_map),
+    ]
+
+
+def slab_meta_views(colidx, values, rowloc):
+    """``[B, C]`` slab metadata -> the ``[B, 1, C]`` views the kernels take."""
+    B, C = colidx.shape
+    return (colidx.reshape(B, 1, C), values.reshape(B, 1, C),
+            rowloc.reshape(B, 1, C))
+
+
+def reduce_slab(values_ref, rowloc_ref, gathered: jax.Array,
+                R: int) -> jax.Array:
+    """Intra-block segment reduction ``[R, C] @ [C, F_tile]`` on the MXU.
+
+    The one-hot row map carries each slot's edge value, so padding slots
+    (value 0) contribute nothing. HIGHEST precision keeps the f32 products
+    exact on the MXU's multi-pass path.
+    """
+    vals = values_ref[0].astype(jnp.float32)              # [1, C]
+    rloc = rowloc_ref[0]                                  # [1, C]
+    C = vals.shape[1]
+    weights = jnp.where(
+        rloc == jax.lax.broadcasted_iota(jnp.int32, (R, C), 0),
+        vals, 0.0)                                        # [R, C]
+    return jax.lax.dot_general(
+        weights, gathered, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _spmm_kernel(colidx_ref, values_ref, rowloc_ref, x_ref, out_ref,
+                 gathered, *, C, R):
     """One block x one feature tile.
 
-    colidx_ref: int32[1, C]; values_ref: f32[1, C]; rowloc_ref: int32[1, C]
-    x_ref: [N_pad, F_tile] feature tile (VMEM resident)
-    out_ref: [1, R, F_tile]
+    colidx_ref: int32[1, 1, C] SMEM; values_ref: f32[1, 1, C];
+    rowloc_ref: int32[1, 1, C]; x_ref: [N_pad, F_tile] feature tile (VMEM
+    resident); out_ref: [1, R, F_tile]; gathered: f32[C, F_tile] scratch.
     """
-    cols = colidx_ref[0, :]                      # [C]
-    vals = values_ref[0, :].astype(jnp.float32)  # [C]
-    rloc = rowloc_ref[0, :]                      # [C]
+    # Gather C dense rows from the feature tile: one scalar-addressed,
+    # full-lane row read per slot (padding slots read a valid row and are
+    # zeroed by their value in the reduction).
+    def gather(k, carry):
+        col = colidx_ref[0, 0, k]
+        gathered[pl.ds(k, 1), :] = x_ref[pl.ds(col, 1), :].astype(jnp.float32)
+        return carry
 
-    # Gather C dense rows from the feature tile. On TPU this lowers to C
-    # dynamic VMEM reads of one (8x128-aligned) row each; lanes are fully
-    # coalesced because the feature tile is the minor dimension.
-    gathered = x_ref[cols, :].astype(jnp.float32)            # [C, F_tile]
-    gathered = gathered * vals[:, None]
-
-    # Intra-block segment reduction as a one-hot MXU matmul (replaces
-    # shared-memory atomics). Padding slots carry value 0 so their one-hot
-    # row contributes nothing.
-    onehot = (rloc[None, :] == jax.lax.broadcasted_iota(jnp.int32, (R, C), 0)
-              ).astype(jnp.float32)                          # [R, C]
-    out_ref[0, :, :] = jax.lax.dot_general(
-        onehot, gathered, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    jax.lax.fori_loop(0, C, gather, 0)
+    out_ref[0] = reduce_slab(values_ref, rowloc_ref, gathered[...], R)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("n_rows", "interpret", "f_tile", "grid_order"),
 )
+def _spmm_block_slabs(colidx, values, rowloc, out_row, x, n_rows, *,
+                      f_tile, grid_order, interpret):
+    if grid_order not in ("block_major", "ft_major"):
+        raise ValueError(
+            f"grid_order must be block_major|ft_major, got {grid_order!r}")
+    B, C = colidx.shape
+    R = out_row.shape[1]
+    N, F = x.shape
+    assert_resident_fits(N, F, C, R, f_tile=f_tile,
+                         itemsize=jnp.dtype(x.dtype).itemsize)
+
+    # Combined-warp alignment: pad F to the lane width (paper's pad-to-32,
+    # scaled to TPU's 128 lanes), pad N to sublane multiple.
+    F_pad = pad_features(F, f_tile)
+    N_pad = pad_rows(N)
+    x_p = jnp.zeros((N_pad, F_pad), x.dtype).at[:N, :F].set(x)
+    nf = F_pad // f_tile
+
+    if grid_order == "block_major":
+        grid = (B, nf)
+        block_ix = lambda b, j: (b, 0, 0)       # noqa: E731
+        x_ix = lambda b, j: (0, j)              # noqa: E731
+        out_ix = lambda b, j: (b, 0, j)         # noqa: E731
+    else:  # ft_major: (feature-tile, block) — block axis innermost
+        grid = (nf, B)
+        block_ix = lambda j, b: (b, 0, 0)       # noqa: E731
+        x_ix = lambda j, b: (0, j)              # noqa: E731
+        out_ix = lambda j, b: (b, 0, j)         # noqa: E731
+    out_slabs = pl.pallas_call(
+        functools.partial(_spmm_kernel, C=C, R=R),
+        grid=grid,
+        in_specs=slab_meta_specs(C, block_ix) + [
+            pl.BlockSpec((N_pad, f_tile), x_ix),
+        ],
+        out_specs=pl.BlockSpec((1, R, f_tile), out_ix),
+        out_shape=jax.ShapeDtypeStruct((B, R, F_pad), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((C, f_tile), jnp.float32)],
+        interpret=interpret,
+    )(*slab_meta_views(colidx, values, rowloc), x_p)
+
+    return scatter_block_rows(out_slabs, out_row, n_rows, F)
+
+
 def spmm_block_slabs(
     colidx: jax.Array,   # int32[B, C]
     values: jax.Array,   # f32[B, C]
@@ -111,7 +213,6 @@ def spmm_block_slabs(
     n_rows: int,
     *,
     f_tile: int = DEFAULT_F_TILE,
-    interpret: bool = True,
     grid_order: str = "block_major",
 ) -> jax.Array:
     """Run the Accel-GCN SpMM kernel over packed slabs; returns [n_rows, F].
@@ -134,51 +235,13 @@ def spmm_block_slabs(
     oversized graphs belong to ``spmm_block_slabs_windowed`` or the HBM
     gather kernel — ``backend="auto"`` picks for you.
     """
-    if grid_order not in ("block_major", "ft_major"):
-        raise ValueError(
-            f"grid_order must be block_major|ft_major, got {grid_order!r}")
-    B, C = colidx.shape
-    R = out_row.shape[1]
-    N, F = x.shape
-    assert_resident_fits(N, F, C, R, f_tile=f_tile,
-                         itemsize=jnp.dtype(x.dtype).itemsize)
-
-    # Combined-warp alignment: pad F to the lane width (paper's pad-to-32,
-    # scaled to TPU's 128 lanes), pad N to sublane multiple.
-    F_pad = pad_features(F, f_tile)
-    N_pad = pad_rows(N)
-    x_p = jnp.zeros((N_pad, F_pad), x.dtype).at[:N, :F].set(x)
-    nf = F_pad // f_tile
-
-    if grid_order == "block_major":
-        grid = (B, nf)
-        block_ix = lambda b, j: (b, 0)          # noqa: E731
-        x_ix = lambda b, j: (0, j)              # noqa: E731
-        out_ix = lambda b, j: (b, 0, j)         # noqa: E731
-    else:  # ft_major: (feature-tile, block) — block axis innermost
-        grid = (nf, B)
-        block_ix = lambda j, b: (b, 0)          # noqa: E731
-        x_ix = lambda j, b: (0, j)              # noqa: E731
-        out_ix = lambda j, b: (b, 0, j)         # noqa: E731
-    out_slabs = pl.pallas_call(
-        functools.partial(_spmm_kernel, C=C, R=R),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, C), block_ix),
-            pl.BlockSpec((1, C), block_ix),
-            pl.BlockSpec((1, C), block_ix),
-            pl.BlockSpec((N_pad, f_tile), x_ix),
-        ],
-        out_specs=pl.BlockSpec((1, R, f_tile), out_ix),
-        out_shape=jax.ShapeDtypeStruct((B, R, F_pad), jnp.float32),
-        interpret=interpret,
-    )(colidx, values, rowloc, x_p)
-
-    return scatter_block_rows(out_slabs, out_row, n_rows, F)
+    return _spmm_block_slabs(colidx, values, rowloc, out_row, x, n_rows,
+                             f_tile=f_tile, grid_order=grid_order,
+                             interpret=pallas_interpret())
 
 
 def _spmm_kernel_windowed(colidx_ref, values_ref, rowloc_ref, x_ref, out_ref,
-                          *, C, R, window):
+                          gathered, *, C, R, window):
     """One block x one feature tile x one row window of X.
 
     x_ref: [window, F_tile] — the w-th row window of the padded features.
@@ -187,53 +250,42 @@ def _spmm_kernel_windowed(colidx_ref, values_ref, rowloc_ref, x_ref, out_ref,
     block accumulates across the (sequential) window axis.
     """
     w = pl.program_id(2)
-    cols = colidx_ref[0, :]                      # [C] global column indices
-    vals = values_ref[0, :].astype(jnp.float32)  # [C]
-    rloc = rowloc_ref[0, :]                      # [C]
+    base = w * window
 
-    local = cols - w * window
-    in_window = ((local >= 0) & (local < window)).astype(jnp.float32)
-    local = jnp.clip(local, 0, window - 1)       # keep the gather in bounds
+    def gather(k, carry):
+        local = colidx_ref[0, 0, k] - base
+        inside = (local >= 0) & (local < window)
 
-    gathered = x_ref[local, :].astype(jnp.float32)           # [C, F_tile]
-    gathered = gathered * (vals * in_window)[:, None]
+        @pl.when(inside)
+        def _row():
+            gathered[pl.ds(k, 1), :] = (
+                x_ref[pl.ds(local, 1), :].astype(jnp.float32))
 
-    onehot = (rloc[None, :] == jax.lax.broadcasted_iota(jnp.int32, (R, C), 0)
-              ).astype(jnp.float32)                          # [R, C]
-    contrib = jax.lax.dot_general(
-        onehot, gathered, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+        @pl.when(jnp.logical_not(inside))
+        def _zero():
+            gathered[pl.ds(k, 1), :] = jnp.zeros(
+                (1, gathered.shape[1]), jnp.float32)
+
+        return carry
+
+    jax.lax.fori_loop(0, C, gather, 0)
+    contrib = reduce_slab(values_ref, rowloc_ref, gathered[...], R)
 
     @pl.when(w == 0)
     def _init():
-        out_ref[0, :, :] = contrib
+        out_ref[0] = contrib
 
     @pl.when(w > 0)
     def _accumulate():
-        out_ref[0, :, :] += contrib
+        out_ref[0] += contrib
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("n_rows", "interpret", "f_tile", "window_rows"),
 )
-def spmm_block_slabs_windowed(
-    colidx: jax.Array,   # int32[B, C]
-    values: jax.Array,   # f32[B, C]
-    rowloc: jax.Array,   # int32[B, C]
-    out_row: jax.Array,  # int32[B, R]
-    x: jax.Array,        # [N, F]
-    n_rows: int,
-    *,
-    f_tile: int = DEFAULT_F_TILE,
-    window_rows: int | None = None,
-    interpret: bool = True,
-) -> jax.Array:
-    """Row-window streaming variant: X visits VMEM one ``window_rows`` tile
-    at a time (grid axis 2), so any N fits in the resident budget at the
-    price of one full (B, nf) grid sweep per window. Returns [n_rows, F].
-    """
+def _spmm_block_slabs_windowed(colidx, values, rowloc, out_row, x, n_rows, *,
+                               f_tile, window_rows, interpret):
     B, C = colidx.shape
     R = out_row.shape[1]
     N, F = x.shape
@@ -250,15 +302,33 @@ def spmm_block_slabs_windowed(
     out_slabs = pl.pallas_call(  # revisits of one output block accumulate
         functools.partial(_spmm_kernel_windowed, C=C, R=R, window=window),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, C), lambda b, j, w: (b, 0)),
-            pl.BlockSpec((1, C), lambda b, j, w: (b, 0)),
-            pl.BlockSpec((1, C), lambda b, j, w: (b, 0)),
+        in_specs=slab_meta_specs(C, lambda b, j, w: (b, 0, 0)) + [
             pl.BlockSpec((window, f_tile), lambda b, j, w: (w, j)),
         ],
         out_specs=pl.BlockSpec((1, R, f_tile), lambda b, j, w: (b, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, R, F_pad), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((C, f_tile), jnp.float32)],
         interpret=interpret,
-    )(colidx, values, rowloc, x_p)
+    )(*slab_meta_views(colidx, values, rowloc), x_p)
 
     return scatter_block_rows(out_slabs, out_row, n_rows, F)
+
+
+def spmm_block_slabs_windowed(
+    colidx: jax.Array,   # int32[B, C]
+    values: jax.Array,   # f32[B, C]
+    rowloc: jax.Array,   # int32[B, C]
+    out_row: jax.Array,  # int32[B, R]
+    x: jax.Array,        # [N, F]
+    n_rows: int,
+    *,
+    f_tile: int = DEFAULT_F_TILE,
+    window_rows: int | None = None,
+) -> jax.Array:
+    """Row-window streaming variant: X visits VMEM one ``window_rows`` tile
+    at a time (grid axis 2), so any N fits in the resident budget at the
+    price of one full (B, nf) grid sweep per window. Returns [n_rows, F].
+    """
+    return _spmm_block_slabs_windowed(
+        colidx, values, rowloc, out_row, x, n_rows, f_tile=f_tile,
+        window_rows=window_rows, interpret=pallas_interpret())

@@ -136,7 +136,6 @@ def spmm_batched(
     n_rows_list: Sequence[int],
     *,
     backend: str = "pallas",
-    interpret: bool = True,
     pad_blocks_to: Optional[int] = None,
     return_decision: bool = False,
     grid_order: str = "block_major",
@@ -187,7 +186,7 @@ def spmm_batched(
         out = kernel(
             jnp.asarray(merged["colidx"]), jnp.asarray(merged["values"]),
             jnp.asarray(merged["rowloc"]), jnp.asarray(merged["out_row"]),
-            x_cat, n_out, interpret=interpret, **kernel_kwargs)
+            x_cat, n_out, **kernel_kwargs)
     elif backend == "blocked":
         from .ops import spmm_blocked  # deferred: ops re-exports this module
         out = spmm_blocked(

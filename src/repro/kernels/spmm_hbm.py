@@ -3,22 +3,28 @@
 ``spmm_accel.py`` keeps the feature tile VMEM-resident, which bounds the
 graph at N_pad x 128 x 4B <= 2 MiB per tile (fine for layer-wise GCN
 batches, not for web-scale graphs). This variant keeps X in HBM
-(``memory_space=ANY``) and gathers the C rows a block needs with explicit
-double-buffered DMA — the TPU embedding-gather pattern, driven by the same
-block-partition metadata. VMEM cost is independent of N, so this is the
-fallback regime of ``router.route_spmm`` (N_pad > MAX_WINDOWS x 4096 at
-defaults); cost scales with nnz instead.
+(``memory_space=ANY``, laid out as ``[nf, N_pad, F_tile]`` feature-tile
+planes) and gathers the C rows a block needs with explicit
+one-row DMAs straight into the gathered slab — the TPU embedding-gather
+pattern, driven by the same block-partition metadata. Up to ``DMA_DEPTH``
+row copies are in flight at once on one DMA semaphore (every copy moves the
+same bytes, so each wait retires one copy). VMEM cost is independent of N,
+so this is the fallback regime of ``router.route_spmm`` (N_pad > MAX_WINDOWS
+x 4096 at defaults); cost scales with nnz instead.
 
 Per grid step (C=256, R=64 defaults, f32):
-  row buffers (2 slots)  [2, 1, F_tile]    1 KiB   (one-ROW DMA granularity:
+  gathered slab          [C, F_tile]     128 KiB  (one-ROW DMA granularity:
                                            gathered rows are scattered, so an
                                            8-row slab copy would move 8x the
                                            bytes for one useful row unless
                                            column indices happen to cluster)
-  gathered slab          [C, F_tile]     128 KiB
   out slab               [R, F_tile]      32 KiB  (x2 pipeline buffers)
-  colidx/values/rowloc   3 x [C]           3 KiB  (x2 pipeline buffers)
-  one-hot                [C, R]            64 KiB
+  values/rowloc          2 x [C]           2 KiB  (x2 pipeline buffers)
+  colidx (SMEM)          [C]               1 KiB  (x2 pipeline buffers)
+  weighted one-hot       [R, C]            64 KiB
+
+``colidx`` arrives in SMEM one block per grid step, so each DMA address is
+a scalar read (a DMA address cannot come from a VMEM vector element).
 
 Batched multi-graph slabs (``spmm_batched`` merge) run unchanged: column
 indices arrive pre-shifted into the concatenated feature rows, padded slab
@@ -27,8 +33,7 @@ slots carry value 0 with an in-bounds index, and fully-padded bucket blocks
 block — so block-count bucketing costs bandwidth only for live blocks.
 
 Validated in interpret mode against the same oracle as the resident-X
-kernel; on hardware the DMA issue loop overlaps the one-hot MXU matmul of
-the previous block (grid-level pipelining is left to Mosaic).
+kernel, and compiled by Mosaic on a TPU (``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
@@ -39,107 +44,96 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import pallas_interpret
 from .router import pad_features, pad_rows
-from .spmm_accel import scatter_block_rows
+from .spmm_accel import (
+    reduce_slab, scatter_block_rows, slab_meta_specs, slab_meta_views,
+)
 
 DEFAULT_F_TILE = 128
+DMA_DEPTH = 8   # one-row gather copies in flight per grid step
 
 
 def _kernel(colidx_ref, values_ref, rowloc_ref, x_hbm, out_ref,
-            gathered, row_buf, sem, *, C, R):
-    """colidx/values/rowloc: [1, C] VMEM; x_hbm: [N_pad, F_pad] ANY (the
-    UNTILED padded features — ANY refs see the whole array, so each DMA
-    slices its own [1, F_tile] lane window at grid axis 1);
-    out_ref: [1, R, F_tile]; gathered: [C, F_tile] VMEM scratch;
-    row_buf: [2, 1, F_tile] VMEM scratch; sem: DMA semaphores [2]."""
+            gathered, sem, *, C, R):
+    """colidx_ref: int32[1, 1, C] SMEM; values/rowloc: [1, 1, C] VMEM;
+    x_hbm: [nf, N_pad, F_tile] ANY (the padded features as feature-tile
+    planes — ANY refs see the whole array, so each DMA copies one
+    full-width row of plane ``j``); out_ref: [1, R, F_tile]; gathered:
+    [C, F_tile] VMEM scratch in X's dtype; sem: one DMA semaphore."""
     j = pl.program_id(1)                 # which feature tile this step owns
-    f_tile = row_buf.shape[-1]
-    cols = colidx_ref[0, :]
-    vals = values_ref[0, :].astype(jnp.float32)
-    rloc = rowloc_ref[0, :]
 
     # Bucket-padding blocks carry all-zero values: skip their C-row DMA loop
     # (and never read the uninitialized gather scratch) — a padded dispatch
     # pays grid-step overhead for dead blocks, not HBM bandwidth.
-    live = jnp.any(vals != 0.0)
+    live = jnp.any(values_ref[0] != 0.0)
 
     @pl.when(live)
     def _gather_and_reduce():
-        def issue(slot, k):
-            cp = pltpu.make_async_copy(
-                x_hbm.at[pl.ds(cols[k], 1), pl.ds(j * f_tile, f_tile)],
-                row_buf.at[slot],
-                sem.at[slot],
+        def row_copy(k):
+            return pltpu.make_async_copy(
+                x_hbm.at[j, pl.ds(colidx_ref[0, 0, k], 1)],
+                gathered.at[pl.ds(k, 1)],
+                sem.at[0],
             )
-            cp.start()
 
-        def wait(slot, k):
-            cp = pltpu.make_async_copy(
-                x_hbm.at[pl.ds(cols[k], 1), pl.ds(j * f_tile, f_tile)],
-                row_buf.at[slot],
-                sem.at[slot],
-            )
-            cp.wait()
+        def issue(k, carry):
+            row_copy(k).start()
 
-        # double-buffered gather: issue k+1 while storing k
-        issue(0, 0)
+            @pl.when(k >= DMA_DEPTH)
+            def _retire():
+                row_copy(k - DMA_DEPTH).wait()
 
-        def body(k, _):
-            slot = jax.lax.rem(k, 2)
-            nxt = jax.lax.rem(k + 1, 2)
+            return carry
 
-            @pl.when(k + 1 < C)
-            def _pre():
-                issue(nxt, k + 1)
+        def drain(k, carry):
+            row_copy(k).wait()
+            return carry
 
-            wait(slot, k)
-            gathered[pl.ds(k, 1), :] = row_buf[slot].astype(jnp.float32)
-            return ()
-
-        jax.lax.fori_loop(0, C, body, ())
-
-        g = gathered[...] * vals[:, None]
-        onehot = (rloc[None, :] ==
-                  jax.lax.broadcasted_iota(jnp.int32, (R, C), 0)
-                  ).astype(jnp.float32)
-        out_ref[0, :, :] = jax.lax.dot_general(
-            onehot, g, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        jax.lax.fori_loop(0, C, issue, 0)
+        jax.lax.fori_loop(max(C - DMA_DEPTH, 0), C, drain, 0)
+        out_ref[0] = reduce_slab(values_ref, rowloc_ref,
+                                 gathered[...].astype(jnp.float32), R)
 
     @pl.when(jnp.logical_not(live))
     def _dead_block():
-        out_ref[0, :, :] = jnp.zeros_like(out_ref[0, :, :])
+        out_ref[0] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n_rows", "interpret", "f_tile"))
-def spmm_block_slabs_hbm(colidx, values, rowloc, out_row, x, n_rows,
-                         *, f_tile: int = DEFAULT_F_TILE, interpret: bool = True):
-    """HBM-gather SpMM over packed slabs; returns [n_rows, F] float32."""
+def _spmm_block_slabs_hbm(colidx, values, rowloc, out_row, x, n_rows, *,
+                          f_tile, interpret):
     B, C = colidx.shape
     R = out_row.shape[1]
     N, F = x.shape
     F_pad = pad_features(F, f_tile)
     N_pad = pad_rows(N)
-    x_p = jnp.zeros((N_pad, F_pad), x.dtype).at[:N, :F].set(x)
     nf = F_pad // f_tile
+    # feature-tile planes [nf, N_pad, f_tile]: a one-row DMA must span the
+    # full minor dimension (Mosaic refuses a lane window of a tiled row)
+    x_p = (jnp.zeros((N_pad, F_pad), x.dtype).at[:N, :F].set(x)
+           .reshape(N_pad, nf, f_tile).transpose(1, 0, 2))
 
     out_slabs = pl.pallas_call(
         functools.partial(_kernel, C=C, R=R),
         grid=(B, nf),
-        in_specs=[
-            pl.BlockSpec((1, C), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, C), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, C), lambda b, j: (b, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # X stays in HBM
+        in_specs=slab_meta_specs(C, lambda b, j: (b, 0, 0)) + [
+            pl.BlockSpec(memory_space=pl.ANY),      # X stays in HBM
         ],
         out_specs=pl.BlockSpec((1, R, f_tile), lambda b, j: (b, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, R, F_pad), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((C, f_tile), jnp.float32),
-            pltpu.VMEM((2, 1, f_tile), x_p.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((C, f_tile), x_p.dtype),
+            pltpu.SemaphoreType.DMA((1,)),
         ],
         interpret=interpret,
-    )(colidx, values, rowloc, x_p)
+    )(*slab_meta_views(colidx, values, rowloc), x_p)
 
     return scatter_block_rows(out_slabs, out_row, n_rows, F)
+
+
+def spmm_block_slabs_hbm(colidx, values, rowloc, out_row, x, n_rows,
+                         *, f_tile: int = DEFAULT_F_TILE):
+    """HBM-gather SpMM over packed slabs; returns [n_rows, F] float32."""
+    return _spmm_block_slabs_hbm(colidx, values, rowloc, out_row, x, n_rows,
+                                 f_tile=f_tile, interpret=pallas_interpret())

@@ -40,7 +40,7 @@ from ..models import attention as attention_mod
 from ..models import lm
 from ..sharding import cache_specs, param_specs, set_mesh_ctx
 from ..train.step import init_train_state, make_train_step
-from .mesh import make_production_mesh
+from .mesh import as_auto_mesh, make_production_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -117,6 +117,7 @@ def build_cell(cfg: ArchConfig, shape: ShapeConfig, mesh, *, chunks=None):
 
 
 def lower_and_compile(cfg, shape, mesh, *, chunks=None, unroll=False):
+    mesh = as_auto_mesh(mesh)
     lm.SCAN_UNROLL = unroll
     attention_mod.SCAN_UNROLL = unroll
     set_mesh_ctx(mesh)

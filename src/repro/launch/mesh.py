@@ -10,13 +10,29 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the models shard activations with
+    ``with_sharding_constraint``, which Explicit axes (the make_mesh
+    default since JAX 0.7) reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
+def as_auto_mesh(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis Auto (a mesh from a
+    plain ``jax.make_mesh`` call comes back Explicit)."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -28,7 +44,7 @@ def make_host_mesh(model: int = 1):
         raise ValueError(
             f"cannot build a ({n // model if model else 0}, {model}) host "
             f"mesh: {n} available device(s) not divisible by model={model}")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def multihost_graph_mesh() -> Mesh:

@@ -454,8 +454,7 @@ class FleetGraphEngine(GraphServeEngine):
                  else jnp.concatenate(feats, axis=1))
             with jax.default_device(self.devices[dev]):
                 outs = spmm_batched([plan.slabs], [x], [plan.n_rows],
-                                    backend=self.backend,
-                                    interpret=self.interpret)
+                                    backend=self.backend)
             out = outs[0][plan.inv_perm]
             answers, _ = self._slice_answers(pending, widths, out,
                                              time.perf_counter())

@@ -114,7 +114,6 @@ class GraphServeEngine:
         cache: Optional[PlanCache] = None,
         cache_capacity: int = 32,
         backend: str = "blocked",
-        interpret: bool = True,
         max_graphs_per_batch: int = 8,
         block_bucket: Optional[int] = 8,
         max_batch_requests: Optional[int] = None,
@@ -130,7 +129,6 @@ class GraphServeEngine:
         if backend not in _BACKENDS:
             raise ValueError(f"backend must be one of {'|'.join(_BACKENDS)}")
         self.backend = backend
-        self.interpret = interpret
         self.max_graphs_per_batch = max_graphs_per_batch
         # min bucket tier: power-of-two tiers from here cap padding waste
         # below 2x the live blocks (the old fixed-256 floor padded a 3-block
@@ -638,8 +636,7 @@ class GraphServeEngine:
         backend, grid_order = self._effective_launch(plans)
         outs, decision = spmm_batched(
             [p.slabs for p in plans], xs, [p.n_rows for p in plans],
-            backend=backend, interpret=self.interpret,
-            pad_blocks_to=pad_to, return_decision=True,
+            backend=backend, pad_blocks_to=pad_to, return_decision=True,
             grid_order=grid_order)
         jax.block_until_ready(outs)
         dt = time.perf_counter() - t0         # this dispatch's kernel time
@@ -795,8 +792,8 @@ class GraphServeEngine:
                 t0 = time.perf_counter()
                 jax.block_until_ready(spmm_batched(
                     [plan.slabs], [x], [plan.n_rows],
-                    backend=backend, interpret=self.interpret,
-                    pad_blocks_to=pad_to, grid_order=grid_order))
+                    backend=backend, pad_blocks_to=pad_to,
+                    grid_order=grid_order))
                 return time.perf_counter() - t0
 
             _once("cand")           # warmup: compilation must not score

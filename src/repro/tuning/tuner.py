@@ -230,7 +230,6 @@ def tune_offline(
     feat_dim: int = 32,
     repeats: int = 3,
     backend: str = "blocked",
-    interpret: bool = True,
     candidates: Optional[Sequence[TuningCandidate]] = None,
     seed: int = 0,
 ) -> Dict:
@@ -255,8 +254,7 @@ def tune_offline(
     def _measure(cfg: PartitionConfig, be: Optional[str],
                  grid_order: str) -> float:
         plan = build_partition_plan(g, cfg)
-        kw = dict(backend=be or backend, interpret=interpret,
-                  grid_order=grid_order)
+        kw = dict(backend=be or backend, grid_order=grid_order)
         import jax
         jax.block_until_ready(
             spmm_batched([plan.slabs], [x], [plan.n_rows], **kw))

@@ -17,7 +17,7 @@ def _case(E, K, N, mt, sizes, seed=0, dtype=np.float32):
     w = rng.normal(size=(E, K, N)).astype(dtype) * 0.2
     be = np.repeat(np.arange(E), gsz // mt).astype(np.int32)
     out = grouped_matmul(jnp.asarray(x), jnp.asarray(w), jnp.asarray(be),
-                         m_tile=mt, interpret=True)
+                         m_tile=mt)
     ref = grouped_matmul_ref(jnp.asarray(x), jnp.asarray(w), jnp.asarray(gsz))
     return np.asarray(out), np.asarray(ref)
 

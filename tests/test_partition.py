@@ -163,7 +163,7 @@ def test_boundary_degree_pattern_path_and_kernel_parity(mode):
                                atol=1e-4, rtol=1e-4)
     jslabs = {k: (jnp.asarray(v) if isinstance(v, np.ndarray) else v)
               for k, v in slabs.items()}
-    out_pallas = spmm_pallas(jslabs, x, g.n_rows, interpret=True)
+    out_pallas = spmm_pallas(jslabs, x, g.n_rows)
     np.testing.assert_allclose(np.asarray(out_pallas), ref,
                                atol=1e-4, rtol=1e-4)
 
@@ -231,7 +231,7 @@ def test_admissible_override_bit_identical_on_both_backends(n, seed, mode,
         np.testing.assert_array_equal(np.asarray(out_b), ref)
         jslabs = {k: (jnp.asarray(v) if isinstance(v, np.ndarray) else v)
                   for k, v in slabs.items()}
-        out_p = spmm_pallas(jslabs, x, g.n_rows, interpret=True)
+        out_p = spmm_pallas(jslabs, x, g.n_rows)
         np.testing.assert_array_equal(np.asarray(out_p), ref)
 
 

@@ -21,7 +21,7 @@ def _run(g, X, mode="tpu", mbw=32, mwn=8, kernel=None):
     out_sorted = kern(
         jnp.asarray(slabs["colidx"]), jnp.asarray(slabs["values"]),
         jnp.asarray(slabs["rowloc"]), jnp.asarray(slabs["out_row"]),
-        jnp.asarray(X), gs.n_rows, interpret=True)
+        jnp.asarray(X), gs.n_rows)
     out = np.empty_like(np.asarray(out_sorted))
     out[gs.perm] = np.asarray(out_sorted)
     return out
