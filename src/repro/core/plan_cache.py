@@ -39,6 +39,7 @@ from .partition import (
     get_partition_patterns,
     pack_slabs,
 )
+from .spans import PLAN_BUILD, span
 
 __all__ = [
     "PartitionConfig",
@@ -222,6 +223,8 @@ class PlanCache:
         self.misses = 0
         self.evictions = 0
         self.builds = 0
+        self.build_s = 0.0      # seconds in build_fn (the gcn.plan.build
+        #                         span), disk reloads excluded
         self.spills = 0
         self.disk_hits = 0
         self.publishes = 0
@@ -268,11 +271,14 @@ class PlanCache:
             try:
                 plan = self._load_from_disk(key)
                 built = plan is None
+                timed: Dict[str, float] = {}
                 if built:
-                    plan = build_fn()
+                    with span(PLAN_BUILD, timed):
+                        plan = build_fn()
                 with self._lock:
                     if built:
                         self.builds += 1
+                        self.build_s += timed[PLAN_BUILD]
                     else:
                         self.disk_hits += 1
                     evicted = self._insert_locked(key, plan)
@@ -530,6 +536,7 @@ class PlanCache:
                 "hits": self.hits,
                 "misses": self.misses,
                 "builds": self.builds,
+                "build_s": self.build_s,
                 "evictions": self.evictions,
                 "spills": self.spills,
                 "disk_hits": self.disk_hits,

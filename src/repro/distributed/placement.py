@@ -446,9 +446,10 @@ class FleetPlanCache:
         """Aggregate counters + per-shard occupancy (for balance stats)."""
         per = [s.stats() for s in self.shards]
         agg: Dict[str, float] = {}
-        for k in ("size", "lookups", "hits", "misses", "builds", "evictions",
-                  "spills", "disk_hits", "device_bytes", "publishes", "pins",
-                  "retired_versions", "retired_reclaimed", "retired_live"):
+        for k in ("size", "lookups", "hits", "misses", "builds", "build_s",
+                  "evictions", "spills", "disk_hits", "device_bytes",
+                  "publishes", "pins", "retired_versions",
+                  "retired_reclaimed", "retired_live"):
             agg[k] = sum(p[k] for p in per)
         total = agg["hits"] + agg["misses"]
         agg["capacity"] = self.capacity_per_device * len(self.shards)

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.spans import LAUNCH, MERGE, PREPARE, UPLOAD, span
 from .router import RoutingDecision, route_spmm
 from .spmm_accel import spmm_block_slabs, spmm_block_slabs_windowed
 from .spmm_hbm import spmm_block_slabs_hbm
@@ -139,6 +140,7 @@ def spmm_batched(
     pad_blocks_to: Optional[int] = None,
     return_decision: bool = False,
     grid_order: str = "block_major",
+    phases: Optional[Dict[str, float]] = None,
 ) -> List[jax.Array] | Tuple[List[jax.Array], Optional[RoutingDecision]]:
     """Fused SpMM over several graphs; returns one ``[n_rows_g, F_g]`` output
     per graph (degree-sorted row order, same as the single-graph kernel).
@@ -157,46 +159,53 @@ def spmm_batched(
     kernel's grid iteration order (see
     :func:`repro.kernels.spmm_accel.spmm_block_slabs`); dispatches that
     route to the windowed/HBM kernels ignore it.
+
+    The body runs as the ``gcn.dispatch`` phases ``merge`` (host slab
+    merge), ``prepare`` (feature concat), ``upload`` (the four merged slab
+    arrays to the device) and ``launch`` (route and the asynchronous kernel
+    call), each a span of :mod:`repro.core.spans`; ``phases``, if given, is
+    the caller's dict that gains each phase's seconds.
     """
     G = len(slab_list)
     assert G == len(x_list) == len(n_rows_list) and G > 0
+    if backend not in ("pallas", "windowed", "hbm", "auto", "blocked"):
+        raise ValueError(f"batched spmm backend must be "
+                         f"auto|pallas|windowed|hbm|blocked, got {backend!r}")
     n_cols_list = [int(x.shape[0]) for x in x_list]
     f_list = [int(x.shape[1]) for x in x_list]
     F = max(f_list)
 
-    merged, out_off, _, n_out = batch_graph_slabs(
-        slab_list, list(n_rows_list), n_cols_list, pad_blocks_to=pad_blocks_to)
+    with span(MERGE, phases):
+        merged, out_off, _, n_out = batch_graph_slabs(
+            slab_list, list(n_rows_list), n_cols_list,
+            pad_blocks_to=pad_blocks_to)
 
-    x_cat = jnp.concatenate(
-        [jnp.pad(jnp.asarray(x, dtype=jnp.float32),
-                 ((0, 0), (0, F - f))) if f < F
-         else jnp.asarray(x, dtype=jnp.float32)
-         for x, f in zip(x_list, f_list)], axis=0)
+    with span(PREPARE, phases):
+        x_cat = jnp.concatenate(
+            [jnp.pad(jnp.asarray(x, dtype=jnp.float32),
+                     ((0, 0), (0, F - f))) if f < F
+             else jnp.asarray(x, dtype=jnp.float32)
+             for x, f in zip(x_list, f_list)], axis=0)
+
+    with span(UPLOAD, phases):
+        slabs = [jnp.asarray(merged[k])
+                 for k in ("colidx", "values", "rowloc", "out_row")]
 
     decision: Optional[RoutingDecision] = None
-    n_x = int(x_cat.shape[0])  # sum of n_cols — the quantity that overflows
-    if backend in ("pallas", "windowed", "hbm", "auto"):
-        force = {"pallas": "resident",
-                 "windowed": "windowed", "hbm": "hbm"}.get(backend)
-        decision = route_spmm(n_x, F, int(merged["C"]),
-                              int(merged["R"]), force=force)
-        kernel = _PALLAS_KERNELS[decision.backend]
-        kernel_kwargs = ({"grid_order": grid_order}
-                         if decision.backend == "resident" else {})
-        out = kernel(
-            jnp.asarray(merged["colidx"]), jnp.asarray(merged["values"]),
-            jnp.asarray(merged["rowloc"]), jnp.asarray(merged["out_row"]),
-            x_cat, n_out, **kernel_kwargs)
-    elif backend == "blocked":
-        from .ops import spmm_blocked  # deferred: ops re-exports this module
-        out = spmm_blocked(
-            jnp.asarray(merged["colidx"]), jnp.asarray(merged["values"]),
-            jnp.asarray(merged["rowloc"]), jnp.asarray(merged["out_row"]),
-            x_cat, n_out)
-    else:
-        raise ValueError(f"batched spmm backend must be "
-                         f"auto|pallas|windowed|hbm|blocked, got {backend!r}")
-
-    outs = [out[int(out_off[i]):int(out_off[i + 1]), :f_list[i]]
-            for i in range(G)]
+    with span(LAUNCH, phases):
+        if backend == "blocked":
+            from .ops import spmm_blocked  # deferred: ops re-exports this
+            out = spmm_blocked(*slabs, x_cat, n_out)
+        else:
+            force = {"pallas": "resident",
+                     "windowed": "windowed", "hbm": "hbm"}.get(backend)
+            # sum of n_cols: the quantity that overflows the resident tile
+            decision = route_spmm(int(x_cat.shape[0]), F, int(merged["C"]),
+                                  int(merged["R"]), force=force)
+            kernel_kwargs = ({"grid_order": grid_order}
+                             if decision.backend == "resident" else {})
+            out = _PALLAS_KERNELS[decision.backend](
+                *slabs, x_cat, n_out, **kernel_kwargs)
+        outs = [out[int(out_off[i]):int(out_off[i + 1]), :f_list[i]]
+                for i in range(G)]
     return (outs, decision) if return_decision else outs

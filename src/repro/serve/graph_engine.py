@@ -41,7 +41,12 @@ Tuning knobs:
   (backpressure) or raises with ``submit(..., block=False)``.
 
 Per-request (enqueue->answer) latency comes from the scheduler's WorkItem
-clock; per-dispatch kernel time accumulates separately in ``total_serve_s``.
+clock. Each dispatch's host wall time, from its start through
+``block_until_ready`` (feature prep, slab merge, upload, launch and the wait
+on the device, not the kernel alone), accumulates in ``total_serve_s``, and
+by phase in ``dispatch_{prepare,merge,upload,launch,wait}_s``;
+``dispatch_answer_s`` times the un-permute and slicing after it. Each phase
+is also a span on the profiler's clock (:mod:`repro.core.spans`).
 ``stats()`` merges engine counters, plan-cache counters (``cache_*``) and
 scheduler counters (``sched_*``).
 """
@@ -51,7 +56,6 @@ import dataclasses
 import logging
 import threading
 import time
-from collections import OrderedDict, deque
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -61,9 +65,10 @@ import numpy as np
 
 from ..core.graph import CSRGraph, gcn_normalize
 from ..core.plan_cache import (
-    PartitionConfig, PartitionPlan, PlanCache, _config_tag,
+    PartitionConfig, PartitionPlan, PlanCache,
     build_partition_plan, graph_content_hash,
 )
+from ..core import spans
 from ..core.plan_repair import EdgeDelta, delta_chain_hash, repair_plan
 from ..kernels.router import RoutingDecision
 from ..kernels.spmm_batched import bucket_blocks, spmm_batched
@@ -76,12 +81,6 @@ __all__ = ["GraphRequest", "GraphServeEngine"]
 logger = logging.getLogger(__name__)
 
 _BACKENDS = ("auto", "pallas", "windowed", "hbm", "blocked")
-
-# per-plan dispatch timing ring: last N wall times per plan key, bounded
-# to the most recently dispatched keys so a graph-churn workload can't
-# grow the map without bound
-PLAN_TIMING_RING = 64
-PLAN_TIMING_KEYS = 256
 
 
 @dataclasses.dataclass
@@ -172,7 +171,14 @@ class GraphServeEngine:
         self.graphs_dispatched = 0   # distinct graphs summed over dispatches
         self.rows_served = 0
         self.values_served = 0       # rows * feature columns
-        self.total_serve_s = 0.0     # sum of per-DISPATCH kernel wall times
+        # host wall time per dispatch, from its start through
+        # block_until_ready: feature prep, slab merge, upload, launch and
+        # the wait on the device, summed over dispatches
+        self.total_serve_s = 0.0
+        # total_serve_s split by phase (spans.DISPATCH_PHASES), plus the
+        # answer phase after it; exposed as dispatch_<phase>_s
+        self.dispatch_phase_s: Dict[str, float] = dict.fromkeys(
+            spans.DISPATCH_PHASES, 0.0)
         self.total_request_latency_s = 0.0  # sum of enqueue->answer times
         self.live_blocks = 0         # merged blocks carrying real slabs
         self.padded_blocks = 0       # blocks actually dispatched (bucketed)
@@ -184,12 +190,6 @@ class GraphServeEngine:
         self.mutation_edges = 0      # edge inserts+deletes applied
         self.plan_repairs = 0        # publishes served by incremental repair
         self.plan_rebuilds = 0       # publishes that fell back to full build
-        # per-plan dispatch wall times: key -> deque of (seconds, exact)
-        # where exact=True means the dispatch held ONLY this plan (a fused
-        # multi-graph dispatch records its per-plan SHARE, flagged inexact).
-        # Appended under _counters_lock on the dispatch path; stats() and
-        # the tuner's incumbent estimate read it there too.
-        self._plan_times: "OrderedDict[tuple, deque]" = OrderedDict()
         # --- online partition autotuning (shadow-measured rollout) -------
         # The tuner only ever acts on COPIES of live work: a shadow
         # duplicates one dispatch onto the candidate plan on a separate
@@ -608,26 +608,32 @@ class GraphServeEngine:
             self._keys[gid] = plan.key
             self._versions[gid] = plan.version
 
+    @spans.span(spans.DISPATCH)
     def _dispatch(self, batch: List[Tuple[str, List[WorkItem],
                                           PartitionPlan]]) -> None:
-        """One fused kernel call over up to max_graphs_per_batch graphs."""
+        """One fused kernel call over up to max_graphs_per_batch graphs,
+        traced as the ``gcn.dispatch`` span and its phases
+        (:mod:`repro.core.spans`), each phase timed into the engine's
+        ``dispatch_<phase>_s`` counter."""
         t0 = time.perf_counter()
+        phases: Dict[str, float] = {}
         plans: List[PartitionPlan] = []
         xs: List[jax.Array] = []
         col_splits: List[List[int]] = []
-        for _gid, grp, plan in batch:
-            feats = [jnp.asarray(it.payload[1], dtype=jnp.float32)
-                     for it in grp]
-            plans.append(plan)
-            x = (feats[0] if len(feats) == 1
-                 else jnp.concatenate(feats, axis=1))
-            if self.feature_bucket:
-                w = int(x.shape[1])
-                pad = bucket_blocks(w, 1) - w   # next power of two
-                if pad:
-                    x = jnp.pad(x, ((0, 0), (0, pad)))
-            xs.append(x)
-            col_splits.append([int(f.shape[1]) for f in feats])
+        with spans.span(spans.PREPARE, phases):
+            for _gid, grp, plan in batch:
+                feats = [jnp.asarray(it.payload[1], dtype=jnp.float32)
+                         for it in grp]
+                plans.append(plan)
+                x = (feats[0] if len(feats) == 1
+                     else jnp.concatenate(feats, axis=1))
+                if self.feature_bucket:
+                    w = int(x.shape[1])
+                    pad = bucket_blocks(w, 1) - w   # next power of two
+                    if pad:
+                        x = jnp.pad(x, ((0, 0), (0, pad)))
+                xs.append(x)
+                col_splits.append([int(f.shape[1]) for f in feats])
 
         b_total = sum(p.num_blocks for p in plans)
         pad_to = None
@@ -637,20 +643,19 @@ class GraphServeEngine:
         outs, decision = spmm_batched(
             [p.slabs for p in plans], xs, [p.n_rows for p in plans],
             backend=backend, pad_blocks_to=pad_to, return_decision=True,
-            grid_order=grid_order)
-        jax.block_until_ready(outs)
-        dt = time.perf_counter() - t0         # this dispatch's kernel time
+            grid_order=grid_order, phases=phases)
+        with spans.span(spans.WAIT, phases):
+            jax.block_until_ready(outs)
+        # host wall time of this dispatch up to ready outputs: the phases
+        # prepare, merge, upload, launch and wait, back to back
+        dt = time.perf_counter() - t0
 
         executed = decision.backend if decision is not None else "blocked"
-        share = dt / len(batch)
         with self._counters_lock:
             self.backend_dispatches[executed] += 1
             self.last_decision = decision
             self.live_blocks += b_total
             self.padded_blocks += pad_to if pad_to else b_total
-            for _, _, plan in batch:
-                self._record_plan_time_locked(plan.key, share,
-                                              len(batch) == 1)
         if logger.isEnabledFor(logging.DEBUG):
             logger.debug(
                 "dispatch: graphs=%d blocks=%d->%d backend=%s (%s) %.1fms",
@@ -664,14 +669,16 @@ class GraphServeEngine:
         answers: List[Tuple[WorkItem, jax.Array]] = []
         n_req = n_rows = n_vals = 0
         wait_s = 0.0
-        for (_gid, grp, plan), out, widths in zip(batch, outs, col_splits):
-            out = out[plan.inv_perm]          # back to original row order
-            sliced, wait = self._slice_answers(grp, widths, out, now)
-            answers.extend(sliced)
-            n_req += len(grp)
-            n_rows += plan.n_rows * len(grp)
-            n_vals += plan.n_rows * sum(widths)
-            wait_s += wait
+        with spans.span(spans.ANSWER, phases):
+            for (_gid, grp, plan), out, widths in zip(batch, outs,
+                                                       col_splits):
+                out = out[plan.inv_perm]      # back to original row order
+                sliced, wait = self._slice_answers(grp, widths, out, now)
+                answers.extend(sliced)
+                n_req += len(grp)
+                n_rows += plan.n_rows * len(grp)
+                n_vals += plan.n_rows * sum(widths)
+                wait_s += wait
         # only the increments sit under the lock (concurrent fleet device
         # launches must not serialize their un-permute/slice work on it)
         with self._counters_lock:
@@ -682,6 +689,8 @@ class GraphServeEngine:
             self.batches_dispatched += 1
             self.graphs_dispatched += len(batch)
             self.total_serve_s += dt
+            for name, seconds in phases.items():
+                self.dispatch_phase_s[name] += seconds
         for item, result in answers:
             item.complete(result)
         # autotuning LAST: every live answer above already resolved, so
@@ -702,17 +711,6 @@ class GraphServeEngine:
         if len(pairs) == 1:
             return pairs.pop()
         return self.backend, "block_major"
-
-    def _record_plan_time_locked(self, key: tuple, seconds: float,
-                                 exact: bool) -> None:
-        ring = self._plan_times.get(key)
-        if ring is None:
-            ring = self._plan_times[key] = deque(maxlen=PLAN_TIMING_RING)
-            while len(self._plan_times) > PLAN_TIMING_KEYS:
-                self._plan_times.popitem(last=False)
-        else:
-            self._plan_times.move_to_end(key)
-        ring.append((seconds, exact))
 
     def _tuner_tick(self, batch, xs, dt: float) -> None:
         """Per-dispatch tuner hook (runs AFTER the live futures resolved).
@@ -856,29 +854,6 @@ class GraphServeEngine:
         logger.info("promoted tuned config for %r: %s (version %d)",
                     gid, cand.label, plan_c.version)
 
-    def plan_timings(self) -> Dict[str, Dict[str, float]]:
-        """Per-plan dispatch timing summary from the bounded ring buffers.
-
-        Keyed ``<graph_hash[:12]>:<config_tag[:8]>`` (hash alone is
-        ambiguous once the tuner publishes a re-configured plan of the
-        same content). ``exact_n`` counts single-graph samples — fused
-        multi-graph dispatches contribute their per-plan share only.
-        """
-        with self._counters_lock:
-            snap = {k: list(ring) for k, ring in self._plan_times.items()}
-        out: Dict[str, Dict[str, float]] = {}
-        for key, samples in snap.items():
-            times = [s for s, _ in samples]
-            tag = f"{key[0][:12]}:{_config_tag(key[1])[:8]}"
-            out[tag] = {
-                "n": len(times),
-                "exact_n": sum(1 for _, e in samples if e),
-                "last_s": times[-1],
-                "mean_s": float(np.mean(times)),
-                "p50_s": float(np.median(times)),
-            }
-        return out
-
     # ------------------------------------------------------------------ stats
     def stats(self) -> Dict[str, float]:
         s = {f"cache_{k}": v for k, v in self.cache.stats().items()}
@@ -887,7 +862,6 @@ class GraphServeEngine:
         if self.tuner is not None:
             s.update({f"tuner_{k}": v
                       for k, v in self.tuner.stats().items()})
-        s["plan_timings"] = self.plan_timings()
         # engine counters are one atomic snapshot (same guarantee as
         # PlanCache.stats()); cache/scheduler snapshots above are each
         # internally consistent but taken a moment earlier
@@ -902,6 +876,10 @@ class GraphServeEngine:
             rows_served=self.rows_served,
             values_served=self.values_served,
             total_serve_s=self.total_serve_s,
+            # total_serve_s by phase (prepare, merge, upload, launch, wait
+            # add up to it within timer overhead), then the answer phase
+            **{f"dispatch_{name.rsplit('.', 1)[1]}_s": seconds
+               for name, seconds in self.dispatch_phase_s.items()},
             requests_per_batch=(self.requests_served / self.batches_dispatched
                                 if self.batches_dispatched else 0.0),
             # cross-caller coalescing: >1 means fused multi-graph dispatches
@@ -920,7 +898,7 @@ class GraphServeEngine:
             padded_blocks=self.padded_blocks,
             block_pad_ratio=(self.padded_blocks / self.live_blocks
                              if self.live_blocks else 0.0),
-            # latency: per-dispatch kernel time vs per-request wait
+            # latency: per-dispatch host wall time vs per-request wait
             avg_dispatch_s=(self.total_serve_s / self.batches_dispatched
                             if self.batches_dispatched else 0.0),
             avg_request_latency_s=(

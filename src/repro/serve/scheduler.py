@@ -35,8 +35,11 @@ Components:
   ``submit_many`` enqueue (with backpressure: block, or raise
   :class:`QueueFullError` with ``block=False``); ``take_ready`` lets a
   running flush pull newly-arrived work mid-flight (the token engine's
-  slot reuse); ``stats()`` reports queue depth, flush-reason counts and
-  latency percentiles — one stats vocabulary for both engines.
+  slot reuse); ``stats()`` reports queue depth, flush-reason counts,
+  summed queue wait (``queue_wait_s``: enqueue to the flush, or the
+  ``take_ready``, that took each item) and latency percentiles — one
+  stats vocabulary for both engines. The worker's deadline wait is the
+  ``sched.hold`` span (:mod:`repro.core.spans`).
 
 Tuning knobs:
 
@@ -81,6 +84,8 @@ import time
 from collections import deque
 from concurrent.futures import Future
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
+
+from ..core.spans import SCHED_HOLD, span
 
 __all__ = ["QueueFullError", "ClassSpec", "WorkItem", "BatchScheduler",
            "percentile"]
@@ -311,6 +316,9 @@ class BatchScheduler:
         self.flushes = 0
         self.items_flushed = 0
         self.mid_flush_admissions = 0  # items pulled by take_ready
+        # summed enqueue -> flush start over items_flushed, and enqueue ->
+        # admission over mid_flush_admissions
+        self.queue_wait_s = 0.0
         self.flush_reasons: Dict[str, int] = {
             "size": 0, "deadline": 0, "drain": 0, "slo": 0}
         self.peak_queue_depth = 0
@@ -582,6 +590,8 @@ class BatchScheduler:
             items = self._take_batch_locked(k)
             if items:
                 self.mid_flush_admissions += len(items)
+                now = time.perf_counter()
+                self.queue_wait_s += sum(now - it.t_enqueue for it in items)
                 self._current_extra.extend(items)
                 self._cond.notify_all()   # wake backpressured submitters
             return items
@@ -614,12 +624,17 @@ class BatchScheduler:
                                 if q) + self.max_wait_ms / 1e3
                     reason = "slo" if next_flush < plain - 1e-9 else "deadline"
                 else:
-                    self._cond.wait(next_flush - now)
+                    # hold the queued items open for co-batching; the
+                    # timeout is taken inside the span, so tracing never
+                    # delays the flush
+                    with span(SCHED_HOLD):
+                        self._cond.wait(next_flush - time.perf_counter())
                     continue
                 batch = self._take_batch_locked(self.max_batch)
                 self.flushes += 1
                 self.flush_reasons[reason] += 1
                 self.items_flushed += len(batch)
+                self.queue_wait_s += sum(now - it.t_enqueue for it in batch)
                 self._current_extra = []
                 self._cond.notify_all()   # queue drained: wake submitters
             try:
@@ -693,6 +708,7 @@ class BatchScheduler:
                 "items_per_flush": (self.items_flushed / self.flushes
                                     if self.flushes else 0.0),
                 "mid_flush_admissions": self.mid_flush_admissions,
+                "queue_wait_s": self.queue_wait_s,
                 "flush_size": self.flush_reasons["size"],
                 "flush_deadline": self.flush_reasons["deadline"],
                 "flush_drain": self.flush_reasons["drain"],
