@@ -10,11 +10,13 @@ makes the same runtime-adaptation argument for varying workloads):
   resident    whole [N_pad, f_tile] in VMEM   N_pad * f_tile * itemsize
   windowed    [window_rows, f_tile] window,   window_rows * f_tile * itemsize
               accumulated over num_windows      (x num_windows grid sweeps)
-  hbm         X stays in HBM; C rows gathered C * f_tile * 4 scratch
-              per block via double-buffer DMA   + 2 * f_tile row buffers
+  hbm         X stays in HBM; C rows gathered C * W * itemsize scratch,
+              per block by one-row DMAs at      W = hbm_gather_width(F_pad)
+              gather width W                    (F_pad up to 2048 at C=256)
 
 This module owns the arithmetic: a per-dispatch VMEM footprint estimate from
-``(N_pad, F_pad, C, R, f_tile)`` and a :func:`route_spmm` that picks the
+``(N_pad, F_pad, C, R, f_tile)``, the HBM kernel's gather width
+(:func:`hbm_gather_width`) and a :func:`route_spmm` that picks the
 cheapest regime that fits the budget. Callers that *force* the resident
 kernel on an oversized dispatch get an explicit :class:`VmemBudgetError`
 at trace time instead of a silent interpret-mode slowdown that would be a
@@ -41,6 +43,7 @@ __all__ = [
     "pad_rows",
     "pad_features",
     "resident_window_rows",
+    "hbm_gather_width",
     "estimate_vmem_bytes",
     "route_spmm",
     "assert_resident_fits",
@@ -102,19 +105,32 @@ def resident_window_rows(f_tile: int = 128, itemsize: int = 4,
     return max(_SUBLANE, (rows // _SUBLANE) * _SUBLANE)
 
 
+def hbm_gather_width(f_pad: int, C: int, itemsize: int = 4) -> int:
+    """Feature width W of the HBM kernel's row gather: the widest multiple
+    of 128 lanes that divides ``f_pad`` and whose ``[C, W]`` gather scratch
+    fits ``X_TILE_BUDGET_BYTES`` (128 if none does). Each block gathers its
+    rows ``f_pad / W`` times; at C=256, f32, once up to F_pad = 2048.
+    """
+    widths = [w for w in range(128, f_pad + 1, 128)
+              if f_pad % w == 0 and C * w * itemsize <= X_TILE_BUDGET_BYTES]
+    return max(widths, default=128)
+
+
 def estimate_vmem_bytes(backend: str, n_pad: int, C: int, R: int,
                         *, f_tile: int = 128, itemsize: int = 4,
-                        window_rows: int | None = None) -> int:
+                        window_rows: int | None = None,
+                        f_pad: int | None = None) -> int:
     """Per-grid-step VMEM footprint estimate of one SpMM dispatch.
 
     Counts the X tile (regime-dependent), the double-buffered slab metadata
     and output block, and the MXU operands (gathered slab + one-hot). The
-    grid dimensions (B blocks x F_pad/f_tile feature tiles) multiply the
-    step *count*, not the per-step footprint, so they do not appear here.
+    grid dimensions (B blocks x feature tiles) multiply the step *count*,
+    not the per-step footprint, so they do not appear here. The resident
+    and windowed kernels step over ``f_tile``-wide feature tiles; the HBM
+    kernel gathers at ``hbm_gather_width(f_pad)`` (``f_pad`` defaults to
+    one tile) straight into its slab, so X costs it no VMEM of its own.
     """
     meta = 2 * 3 * C * 4            # colidx/values/rowloc, double-buffered
-    out = 2 * R * f_tile * 4        # output block, double-buffered
-    gathered = C * f_tile * 4       # [C, f_tile] slab feeding the MXU
     onehot = C * R * 4              # [R, C] segment-reduction operand
     if backend == "resident":
         x_cost = n_pad * f_tile * itemsize
@@ -122,9 +138,14 @@ def estimate_vmem_bytes(backend: str, n_pad: int, C: int, R: int,
         w = window_rows or resident_window_rows(f_tile, itemsize)
         x_cost = 2 * min(n_pad, w) * f_tile * itemsize  # streamed -> 2 bufs
     elif backend == "hbm":
-        x_cost = 2 * 1 * f_tile * itemsize              # 2 one-row DMA bufs
+        width = hbm_gather_width(f_pad or f_tile, C, itemsize)
+        return (meta + onehot
+                + 2 * R * width * 4          # output block, double-buffered
+                + C * width * itemsize)      # [C, W] gathered slab, X's dtype
     else:
         raise ValueError(f"unknown backend {backend!r}")
+    out = 2 * R * f_tile * 4        # output block, double-buffered
+    gathered = C * f_tile * 4       # [C, f_tile] f32 slab feeding the MXU
     return x_cost + meta + out + gathered + onehot
 
 
@@ -142,6 +163,8 @@ class RoutingDecision:
     itemsize: int
     num_windows: int      # 1 for resident; >1 windowed; 0 for hbm
     window_rows: int
+    gather_width: int     # columns per row copy: f_tile, or (hbm)
+                          # hbm_gather_width; f_pad / it gather passes
     vmem_bytes: int       # total per-step estimate for the chosen backend
     resident_bytes: int   # what the forced-resident tile would have cost
     budget_bytes: int     # per-buffer X-tile budget (resident/window cap)
@@ -168,9 +191,9 @@ def route_spmm(n_x_rows: int, n_features: int, C: int, R: int,
 
     Routing picks the first of resident -> windowed -> hbm whose X-tile
     constraint holds AND whose whole-step estimate fits the total VMEM
-    budget; the fixed MXU operands (one-hot ``[R, C]``, gathered ``[C,
-    f_tile]``) are regime-independent, so a partition capacity so large
-    that even the HBM regime overflows raises :class:`VmemBudgetError`
+    budget; the MXU operands (one-hot ``[R, C]``, gathered ``[C, W]`` with
+    ``W >= f_tile``) grow with C in every regime, so a partition capacity so
+    large that even the HBM regime overflows raises :class:`VmemBudgetError`
     (the fix is a smaller ``max_block_warps x max_warp_nzs``, not a
     different kernel).
 
@@ -192,9 +215,11 @@ def route_spmm(n_x_rows: int, n_features: int, C: int, R: int,
             backend=backend, n_rows=int(n_x_rows), n_pad=n_pad, f_pad=f_pad,
             C=int(C), R=int(R), f_tile=f_tile, itemsize=itemsize,
             num_windows=num_windows, window_rows=window,
+            gather_width=(hbm_gather_width(f_pad, C, itemsize)
+                          if backend == "hbm" else f_tile),
             vmem_bytes=estimate_vmem_bytes(
                 backend, n_pad, C, R, f_tile=f_tile, itemsize=itemsize,
-                window_rows=window),
+                window_rows=window, f_pad=f_pad),
             resident_bytes=resident_bytes, budget_bytes=budget_bytes,
             total_budget_bytes=TOTAL_VMEM_BUDGET_BYTES,
             reason=reason)
@@ -242,17 +267,18 @@ def route_spmm(n_x_rows: int, n_features: int, C: int, R: int,
 
     for backend, nw, reason in candidates:
         if estimate_vmem_bytes(backend, n_pad, C, R, f_tile=f_tile,
-                               itemsize=itemsize,
-                               window_rows=window) <= TOTAL_VMEM_BUDGET_BYTES:
+                               itemsize=itemsize, window_rows=window,
+                               f_pad=f_pad) <= TOTAL_VMEM_BUDGET_BYTES:
             return _decision(backend, nw, reason)
     hbm_bytes = estimate_vmem_bytes("hbm", n_pad, C, R, f_tile=f_tile,
-                                    itemsize=itemsize)
+                                    itemsize=itemsize, f_pad=f_pad)
     raise VmemBudgetError(
         f"no SpMM regime fits the total VMEM budget "
         f"({TOTAL_VMEM_BUDGET_BYTES // 1024} KiB): block capacity C={C}, "
         f"R={R} costs {hbm_bytes // 1024} KiB per grid step even with X in "
-        f"HBM (one-hot [R, C] and gathered [C, {f_tile}] MXU operands are "
-        f"regime-independent); repartition with a smaller "
+        f"HBM (one-hot [R, C] and gathered [C, "
+        f"{hbm_gather_width(f_pad, C, itemsize)}] MXU operands); "
+        f"repartition with a smaller "
         f"max_block_warps x max_warp_nzs.")
 
 

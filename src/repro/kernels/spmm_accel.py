@@ -25,17 +25,22 @@ whole ``colidx`` array is never scalar-prefetched: at full size it is far
 larger than SMEM.
 
 VMEM budget per grid step (f32, defaults C=256, R=64, F_tile=128; the
-routing arithmetic lives in ``router.py``):
+HBM kernel's gather width W is ``router.hbm_gather_width``: F_pad up to
+2048 at C=256, so W=256 at F=256; the routing arithmetic lives in
+``router.py``):
 
-  term                          resident          windowed         (hbm: see
-  ----------------------------  ----------------  ---------------  spmm_hbm)
-  X feature tile                [N_pad, F_tile]   [4096, F_tile]
-                                N_pad<=4096: 2MiB  2 MiB x 2 bufs
-  gathered slab [C, F_tile]     128 KiB           128 KiB
-  out slab      [R, F_tile]     32 KiB (x2 bufs)  32 KiB (x2 bufs)
-  values/rowloc [C] (+colidx    3 KiB  (x2 bufs)  3 KiB  (x2 bufs)
-  [C] in SMEM)
-  weighted one-hot [R, C]       64 KiB            64 KiB
+  term              resident          windowed          hbm (spmm_hbm)
+  ----------------  ----------------  ----------------  -----------------
+  X feature tile    [N_pad, F_tile]   [4096, F_tile]    none: rows DMA from
+                    N_pad<=4096: 2MiB  2 MiB x 2 bufs   HBM into the slab
+  gathered slab     [C, F_tile]       [C, F_tile]       [C, W]
+                    128 KiB           128 KiB           C*W*4, <= 2 MiB
+  out slab          [R, F_tile]       [R, F_tile]       [R, W]
+                    32 KiB (x2 bufs)  32 KiB (x2 bufs)  R*W*4 (x2 bufs)
+  values/rowloc [C] 3 KiB  (x2 bufs)  3 KiB  (x2 bufs)  3 KiB  (x2 bufs)
+  (+colidx in SMEM)
+  weighted one-hot  64 KiB            64 KiB            64 KiB
+  [R, C]
 
 * ``spmm_block_slabs`` (resident): the whole X tile sits in VMEM. Guarded —
   N_pad over the 2 MiB tile budget raises ``VmemBudgetError`` at trace time

@@ -184,6 +184,9 @@ class GraphServeEngine:
         self.padded_blocks = 0       # blocks actually dispatched (bucketed)
         self.backend_dispatches: Dict[str, int] = {
             "resident": 0, "windowed": 0, "hbm": 0, "blocked": 0}
+        # row-gather passes each block of an hbm dispatch makes (F_pad / W,
+        # router.hbm_gather_width), summed over hbm dispatches
+        self.hbm_gather_passes = 0
         self.last_decision: Optional[RoutingDecision] = None
         # mutation-path counters (versioned plan lifecycle)
         self.mutations_applied = 0   # mutate() requests resolved
@@ -653,6 +656,9 @@ class GraphServeEngine:
         executed = decision.backend if decision is not None else "blocked"
         with self._counters_lock:
             self.backend_dispatches[executed] += 1
+            if executed == "hbm":
+                self.hbm_gather_passes += (decision.f_pad
+                                           // decision.gather_width)
             self.last_decision = decision
             self.live_blocks += b_total
             self.padded_blocks += pad_to if pad_to else b_total
@@ -892,6 +898,7 @@ class GraphServeEngine:
             routed_resident=self.backend_dispatches["resident"],
             routed_windowed=self.backend_dispatches["windowed"],
             routed_hbm=self.backend_dispatches["hbm"],
+            hbm_gather_passes=self.hbm_gather_passes,
             routed_blocked=self.backend_dispatches["blocked"],
             # block bucketing waste: padded/live == 1.0 means no dead steps
             live_blocks=self.live_blocks,

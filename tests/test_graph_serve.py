@@ -221,6 +221,31 @@ def test_blocked_backend_counts_as_blocked_dispatch():
     assert engine.stats()["routed_blocked"] == 1
 
 
+def test_hbm_gather_passes_count_f_pad_over_gather_width():
+    """Each hbm dispatch adds F_pad / W row-gather passes: one at full width
+    (F=16, F=256), seventeen past the 2 MiB gather budget (F=2100: F_pad
+    2176, W=128, with features left unbucketed); dispatches on other
+    kernels add none."""
+    engine, graphs, feats = _setup(n_graphs=1, backend="hbm",
+                                   feature_bucket=False)
+    g = graphs["g0"]
+    rng = np.random.default_rng(1)
+    passes = []
+    for f in (16, 256, 2100):
+        x = jnp.asarray(rng.normal(size=(g.n_rows, f)), jnp.float32)
+        req = GraphRequest("g0", x)
+        engine.serve([req])
+        np.testing.assert_allclose(
+            np.asarray(req.out), np.asarray(make_accel_spmm(g)(x)),
+            atol=1e-4, rtol=1e-4)
+        passes.append(engine.stats()["hbm_gather_passes"])
+    assert passes == [1, 2, 19]
+    assert engine.stats()["routed_hbm"] == 3
+    other, _, other_feats = _setup(n_graphs=1, backend="pallas")
+    other.serve([GraphRequest("g0", other_feats["g0"])])
+    assert other.stats()["hbm_gather_passes"] == 0
+
+
 def test_per_request_latency_includes_queue_wait():
     """Requests answered by later dispatches of one serve() call must report
     strictly larger enqueue->answer latency than the first dispatch; the

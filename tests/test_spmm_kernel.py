@@ -69,15 +69,26 @@ def test_hypothesis_random_graphs(n, seed, zipf, F):
     np.testing.assert_allclose(out, ref, atol=5e-4, rtol=1e-3)
 
 
-@pytest.mark.parametrize("F", [32, 96, 128, 200])
+@pytest.mark.parametrize("F", [32, 96, 128, 200, 256, 300, 2100])
 def test_hbm_gather_variant(F):
-    """HBM-resident X kernel (double-buffered DMA gather) vs oracle."""
+    """HBM-resident X kernel (pipelined one-row DMA gather) vs oracle: one
+    full-width gather per block up to F_pad 384 here (W = F_pad), and the
+    tiled fallback at F=2100 (F_pad 2176, W = 128: seventeen planes)."""
     from repro.kernels.spmm_hbm import spmm_block_slabs_hbm
     g = gcn_normalize(make_powerlaw_csr(n=140, seed=4))
     X = np.random.default_rng(0).normal(size=(140, F)).astype(np.float32)
     ref = np.asarray(csr_spmm_ref(g.rowptr, g.colidx, g.values, jnp.asarray(X)))
     out = _run(g, X, kernel=spmm_block_slabs_hbm)
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("F,width", [(40, 128), (256, 256), (2048, 2048),
+                                     (2176, 128)])
+def test_hbm_gather_width(F, width):
+    """The widest multiple of 128 dividing F_pad whose [C, W] f32 scratch
+    fits the 2 MiB budget (C=256: W <= 2048); 2176 = 17 x 128 tiles."""
+    from repro.kernels.router import hbm_gather_width, pad_features
+    assert hbm_gather_width(pad_features(F, 128), 256, 4) == width
 
 
 def test_hbm_matches_resident_kernel():

@@ -93,6 +93,16 @@ def test_decision_reports_estimates():
     assert "hbm" in d.describe()
 
 
+def test_hbm_estimate_counts_the_gather_width():
+    """An hbm step holds a [C, W] slab and a double-buffered [R, W] output
+    block at W = hbm_gather_width(F_pad), and the decision carries W."""
+    d = route_spmm(20_000, 256, C, R)
+    assert d.backend == "hbm" and d.gather_width == 256
+    narrow = estimate_vmem_bytes("hbm", d.n_pad, C, R)       # W = 128
+    assert d.vmem_bytes - narrow == (C + 2 * R) * 128 * 4
+    assert route_spmm(20_000, 2176, C, R).gather_width == 128
+
+
 def test_oversized_block_capacity_falls_back_then_raises():
     """The MXU operands scale with C*R in EVERY regime: a partition capacity
     that pushes the resident step over the total budget must route to hbm

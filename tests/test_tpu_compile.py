@@ -20,12 +20,16 @@ from repro.kernels import spmm_accel, spmm_hbm
 C, R = 256, 64   # default PartitionConfig slab capacity (core/partition.py)
 
 # (kernel, B blocks, N feature rows, F): the Arxiv analogue at its published
-# size (169,343 nodes; 6,723 slabs after gcn_normalize) routes to the HBM
-# kernel; a resident dispatch has N <= 4096, a windowed one 4096 < N <= 16384.
+# size (169,343 nodes; 6,397 blocks of the bounded graph) routes to the HBM
+# kernel, which gathers at full width at the model's F=40 and F=256 and
+# tiles past the 2 MiB gather budget (F=2176: W=128, seventeen planes); a
+# resident dispatch has N <= 4096, a windowed one 4096 < N <= 16384.
 CASES = {
     "resident": (spmm_accel.spmm_block_slabs, 256, 4096, 256),
     "windowed": (spmm_accel.spmm_block_slabs_windowed, 512, 12_000, 256),
-    "hbm": (spmm_hbm.spmm_block_slabs_hbm, 6723, 169_343, 256),
+    "hbm": (spmm_hbm.spmm_block_slabs_hbm, 6397, 169_343, 256),
+    "hbm-F40": (spmm_hbm.spmm_block_slabs_hbm, 6397, 169_343, 40),
+    "hbm-F2176": (spmm_hbm.spmm_block_slabs_hbm, 6397, 169_343, 2176),
 }
 
 
