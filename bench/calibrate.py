@@ -30,7 +30,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     args = ap.parse_args(argv)
 
-    from bench import check, harness, system, traffic
+    from bench import check, harness, loader, system
     from repro.launch.compile_cache import enable_compile_cache
     cell = harness.cell_of(harness.load_spec(), args.workload)
     try:
@@ -39,12 +39,12 @@ def main(argv=None) -> int:
         print(f"calibrate: {e}", file=sys.stderr)
         return 2
     enable_compile_cache()
-    config = system.load_json("configs", cell["config"])
-    mix = system.load_json("traffic", cell["traffic"])
+    config = loader.load_json("configs", cell["config"])
+    mix = loader.load_json("traffic", cell["traffic"])
     worst = {}
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
-        loop = traffic.make_driver(config, mix, seed, devices)
+        loop = system.make_loop(config, mix, seed, devices)
         try:
             loop.setup()
             loop.window(args.seconds)

@@ -21,14 +21,13 @@ that is not finite, or an answer of the wrong shape, fails.
 """
 from __future__ import annotations
 
-import json
 import math
-from pathlib import Path
 from typing import Dict, Iterable, Tuple
 
 import numpy as np
 
-LIMITS_DIR = Path(__file__).resolve().parent / "limits"
+from bench import loader
+
 NUMBERS = ("rel_err", "max_err", "med_err", "row_err")
 
 
@@ -68,8 +67,7 @@ def compare(triples: Iterable[Tuple[np.ndarray, np.ndarray, np.ndarray]]
 
 
 def load_limits(workload: str) -> Dict[str, float]:
-    path = LIMITS_DIR / f"{workload}.json"
-    spec = json.loads(path.read_text())
+    spec = loader.load_json("limits", workload)
     return {name: float(v["limit"]) for name, v in spec["limits"].items()}
 
 

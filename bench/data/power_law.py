@@ -1,32 +1,17 @@
-"""The benchmark's own graph data: a bounded power-law generator and GCN
-normalisation.
-
-The normalisation is a copy of the repository's, kept here so that a later
-change to the program's data code cannot move the yardstick. Everything is
-host numpy, deterministic in its arguments, and returns plain
-``(rowptr, colidx, values)`` arrays.
-"""
+"""Graph kind ``power_law``: one simple directed graph with a bounded
+power-law out-degree sequence, from the seed's stream 0."""
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, List
 
 import numpy as np
 
-CSR = Tuple[np.ndarray, np.ndarray, np.ndarray]   # rowptr, colidx, values
+from bench.data import CSR, csr_from_edges, rng_for
 
 
-def rng_for(seed: int, *stream: int) -> np.random.Generator:
-    """Independent generator per (seed, stream); any non-negative seed."""
-    return np.random.default_rng([int(seed) % 2**63, *stream])
-
-
-def csr_from_edges(src: np.ndarray, dst: np.ndarray, n: int) -> CSR:
-    """CSR of an edge list, rows in source order, no deduplication."""
-    order = np.argsort(src, kind="stable")
-    src, dst = src[order], dst[order]
-    rowptr = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(np.bincount(src, minlength=n), out=rowptr[1:])
-    return rowptr, dst.astype(np.int64), np.ones(len(src), np.float32)
+def build(graph_cfg: Dict, seed: int) -> List[CSR]:
+    return [power_law_graph(graph_cfg["nodes"], graph_cfg["edges"],
+                            graph_cfg["max_degree"], rng_for(seed, 0))]
 
 
 def power_law_degrees(n: int, m_edges: int, max_degree: int) -> np.ndarray:
@@ -72,23 +57,3 @@ def power_law_graph(n: int, m_edges: int, max_degree: int,
         again[first] = False
         todo = np.flatnonzero(again | (dst == src))
     return csr_from_edges(src, dst, n)
-
-
-def gcn_normalize(g: CSR) -> CSR:
-    """A' = D^-1/2 (A + I) D^-1/2 with D the row degrees of A + I: a self
-    loop closes every row, values computed in float64, stored float32."""
-    rowptr, colidx, values = g
-    n = len(rowptr) - 1
-    deg = np.diff(rowptr)
-    new_rowptr = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(deg + 1, out=new_rowptr[1:])
-    keep = np.ones(int(new_rowptr[-1]), dtype=bool)
-    keep[new_rowptr[1:] - 1] = False              # last slot of each row
-    cols = np.empty(int(new_rowptr[-1]), dtype=np.int64)
-    vals = np.empty(int(new_rowptr[-1]), dtype=np.float64)
-    cols[keep], vals[keep] = colidx, values
-    cols[~keep], vals[~keep] = np.arange(n), 1.0
-    dinv = 1.0 / np.sqrt(np.diff(new_rowptr).astype(np.float64))
-    row_of = np.repeat(np.arange(n), np.diff(new_rowptr))
-    vals = vals * dinv[row_of] * dinv[cols]
-    return new_rowptr, cols, vals.astype(np.float32)

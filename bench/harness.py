@@ -5,22 +5,26 @@ The steps, in order:
 1. Find the cell in ``BENCHMARK.json`` and its configuration and traffic
    files; refuse a platform that is not a TPU or holds too few chips.
 2. Set-up: JAX's persistent compilation cache in the checkout, writing
-   every program the set-up compiles (``min_compile_time`` 0); the traffic
-   loop builds the system and warms every shape its traffic uses.
+   every program the set-up compiles (``min_compile_time`` 0); the loop of
+   the traffic's kind builds the system (graph kind, model, engine) and
+   warms every shape its traffic uses.
 3. Cache writes off (``min_compile_time`` 1e9): a program compiled inside
    the window is never written, so no later run can find it there.
-4. The measured window, traced with ``--trace 1``. Backend compiles inside
-   it are counted by a ``jax.monitoring`` listener.
+4. The measured window, traced with ``--trace 1``, and also with
+   ``--trace 0`` where an end-to-end metric of the cell comes from the
+   device trace. Backend compiles inside it are counted by a
+   ``jax.monitoring`` listener.
 5. The device's peak memory, then the program's state is freed, and the
    plain reference judges the kept answers against the cell's limits.
 
 The result is one JSON line: with ``--trace 0`` the cell's end-to-end
-metrics, with ``--trace 1`` its per-layer metrics, each read by its own
-file under ``metrics/``.
+metrics, with ``--trace 1`` its per-layer metrics. An end-to-end metric is
+the loop's own where its ``end_to_end()`` reports it; every other metric is
+read by its own file under ``metrics/``. Every part is found by name
+(``loader.py``).
 """
 from __future__ import annotations
 
-import importlib.util
 import json
 import math
 import shutil
@@ -32,11 +36,10 @@ from typing import Dict, Optional, Sequence
 
 import jax
 
-from bench import check, system, traffic
+from bench import check, loader, system
 from bench.work import load_peaks
 
 ROOT = Path(__file__).resolve().parents[1]
-METRICS_DIR = Path(__file__).resolve().parent / "metrics"
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -69,15 +72,6 @@ def metrics_of(spec: Dict, workload: str, section: str) -> Sequence[Dict]:
         elif section == "end_to_end" or m["moves"] in e2e:
             out.append(m)
     return out
-
-
-def load_reader(name: str):
-    path = METRICS_DIR / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod
 
 
 def check_devices(chips: int):
@@ -137,8 +131,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     size."""
     spec = spec or load_spec()
     cell = cell_of(spec, workload)
-    config = config or system.load_json("configs", cell["config"])
-    mix = mix or system.load_json("traffic", cell["traffic"])
+    config = config or loader.load_json("configs", cell["config"])
+    mix = mix or loader.load_json("traffic", cell["traffic"])
     if devices is None:
         devices = check_devices(int(cell["chips"]))
     kind = devices[0].device_kind
@@ -149,19 +143,21 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     counter = CompileCounter()
     jax.monitoring.register_event_duration_secs_listener(counter)
-    loop = traffic.make_driver(config, mix, seed, devices)
+    traced = trace or any(m["source"] == "device_trace" for m
+                          in metrics_of(spec, workload, "end_to_end"))
+    loop = system.make_loop(config, mix, seed, devices)
     try:
         loop.setup()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
-        trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
+        trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if traced else None
         stats0 = loop.engine.stats()
         setup_s = time.perf_counter() - t_start
         counter.on = True
-        if trace:
+        if traced:
             jax.profiler.start_trace(trace_dir)
         with jax.profiler.TraceAnnotation("bench.window"):
             loop.window(seconds)
-        if trace:
+        if traced:
             jax.profiler.stop_trace()
         counter.on = False
         stats1 = loop.engine.stats()
@@ -171,15 +167,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         run = Run(stats0=stats0, stats1=stats1, compiles=counter.count,
                   compile_s=counter.seconds, window_s=loop.window_s,
                   units=loop.units(), work=loop.work(), peaks=peaks,
-                  chips=len(devices), trace=None)
+                  chips=len(devices), end_to_end=e2e, trace=None)
         print(f"[bench] {workload}: window {loop.window_s:.3f} s, "
               f"{counter.count} backend compiles in it "
               f"({counter.seconds:.3f} s)", file=sys.stderr)
         summary = None
-        if trace:
-            from bench.trace import TraceSummary, load_xplane
-            summary = TraceSummary(load_xplane(trace_dir),
-                                   devices=[d.id for d in devices])
+        if traced:
+            from bench.trace import NoMatchingEvents, TraceSummary, load_xplane
+            try:
+                summary = TraceSummary(load_xplane(trace_dir),
+                                       devices=[d.id for d in devices])
+            except NoMatchingEvents:
+                if trace:
+                    raise
             shutil.rmtree(trace_dir, ignore_errors=True)
             run.trace = summary
         answers = loop.answers()
@@ -194,15 +194,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     section = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in metrics_of(spec, workload, section):
-        value = (load_reader(m["name"]).read(run) if trace
-                 else e2e.get(m["name"]))
+        value = (e2e[m["name"]] if not trace and m["name"] in e2e
+                 else loader.load("metrics", m["name"]).read(run))
         if value is not None and math.isfinite(value):
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(devices), "memory_peak_bytes": memory_peak}
     result = {"correct": correct, "attempted": attempted, "failed": failed,
               "metrics": metrics, "device": device}
-    if summary is not None:
+    if trace:
         device.update(busy_s=summary.busy_s(), window_s=summary.window_s)
         result["breakdown"] = {"device_ops": summary.top_modules(10),
                                "idle_gaps": summary.idle_gaps(10)}

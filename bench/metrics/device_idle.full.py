@@ -2,7 +2,7 @@
 the union of the device's op intervals over the window, averaged over the
 devices the cell uses."""
 UNIT = "%"
-MOVES = "forward_ms"
+MOVES = "forward_device_ms"
 
 
 def read(run):

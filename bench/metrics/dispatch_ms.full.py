@@ -2,7 +2,7 @@
 time (``total_serve_s``, merge to ``block_until_ready``) over its
 dispatches, both as changes across the window."""
 UNIT = "ms"
-MOVES = "forward_ms"
+MOVES = "forward_device_ms"
 
 
 def read(run):

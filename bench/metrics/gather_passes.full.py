@@ -5,7 +5,7 @@ gather width) over its ``routed_hbm``, both as changes across the window.
 from bench.spans import change
 
 UNIT = "passes"
-MOVES = "forward_ms"
+MOVES = "forward_device_ms"
 
 
 def read(run):

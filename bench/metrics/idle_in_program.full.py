@@ -9,7 +9,7 @@ import sys
 from bench.spans import HOST_WORK, idle_split
 
 UNIT = "%"
-MOVES = "forward_ms"
+MOVES = "forward_device_ms"
 
 
 def read(run):

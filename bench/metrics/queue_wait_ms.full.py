@@ -5,7 +5,7 @@ window."""
 from bench.spans import change
 
 UNIT = "ms"
-MOVES = "forward_ms"
+MOVES = "forward_device_ms"
 
 
 def read(run):

@@ -9,7 +9,7 @@ from bench.trace import NoMatchingEvents
 from bench.work import compulsory_s
 
 UNIT = "%"
-MOVES = "forward_ms"
+MOVES = "forward_device_ms"
 MODULES = r"_spmm_block_slabs(_windowed|_hbm)?\b"
 
 

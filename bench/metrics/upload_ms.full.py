@@ -4,7 +4,7 @@ dispatches, both as changes across the window."""
 from bench.spans import per_dispatch_ms
 
 UNIT = "ms"
-MOVES = "forward_ms"
+MOVES = "forward_device_ms"
 
 
 def read(run):

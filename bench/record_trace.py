@@ -27,7 +27,7 @@ def main(argv=None) -> int:
 
     import jax
 
-    from bench import harness, system, traffic
+    from bench import harness, loader, system
     from bench.spans import PROGRAM_SPANS
     from bench.trace import (HOST_PLANE, TraceSummary, load_xplane,
                              save_events)
@@ -36,9 +36,9 @@ def main(argv=None) -> int:
     cell = harness.cell_of(harness.load_spec(), args.workload)
     devices = harness.check_devices(int(cell["chips"]))
     enable_compile_cache()
-    loop = traffic.make_driver(system.load_json("configs", cell["config"]),
-                               system.load_json("traffic", cell["traffic"]),
-                               args.seed, devices)
+    loop = system.make_loop(loader.load_json("configs", cell["config"]),
+                            loader.load_json("traffic", cell["traffic"]),
+                            args.seed, devices)
     trace_dir = tempfile.mkdtemp(prefix="bench-record-")
     try:
         loop.setup()

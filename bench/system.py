@@ -1,26 +1,20 @@
 """The system under test, built from a configuration file.
 
-A configuration (``configs/<name>.json``) names its graph data, its model
-widths and the engine's settings. This module turns it into host CSR arrays
-(``bench.data``), registers them with the program's engine, and makes the
-dense inputs from the seed. It is the only place the benchmark touches the
-program, apart from the metric readers' counters.
+A configuration (``configs/<name>.json``) names its graph kind, its model
+kind and widths, and the engine's settings. This module builds the graphs
+by the graph kind's file, puts them through the model's ``prepare``,
+registers them with the program's engine, and makes the traffic kind's loop.
+It is the only place the benchmark touches the program, apart from the
+metric readers' counters.
 """
 from __future__ import annotations
 
-import json
-from pathlib import Path
-from typing import Dict, List
+from typing import Callable, Dict, List, Sequence
 
 import numpy as np
 
-from bench.data import graphs as data
-
-BENCH_DIR = Path(__file__).resolve().parent
-
-
-def load_json(kind: str, name: str) -> Dict:
-    return json.loads((BENCH_DIR / kind / f"{name}.json").read_text())
+from bench import loader
+from bench.data import CSR
 
 
 def jax_seed(seed: int, stream: int) -> int:
@@ -29,15 +23,12 @@ def jax_seed(seed: int, stream: int) -> int:
                .generate_state(1)[0])
 
 
-def build_graphs(graph_cfg: Dict, seed: int) -> List[data.CSR]:
-    """The configuration's graphs, GCN-normalised, from the seed."""
-    kind = graph_cfg["kind"]
-    if kind == "power_law":
-        g = data.power_law_graph(graph_cfg["nodes"], graph_cfg["edges"],
-                                 graph_cfg["max_degree"],
-                                 data.rng_for(seed, 0))
-        return [data.gcn_normalize(g)]
-    raise ValueError(f"unknown graph kind {kind!r}")
+def build_graphs(graph_cfg: Dict, seed: int, prepare: Callable[[CSR], CSR]
+                 ) -> List[CSR]:
+    """The configuration's graphs from the seed, by its graph kind's file,
+    each through the model's ``prepare``."""
+    kind = loader.load("data", graph_cfg["kind"])
+    return [prepare(g) for g in kind.build(graph_cfg, seed)]
 
 
 def make_engine(engine_cfg: Dict):
@@ -46,8 +37,15 @@ def make_engine(engine_cfg: Dict):
     return GraphServeEngine(**engine_cfg)
 
 
-def register(engine, graph_id: str, g: data.CSR) -> None:
+def register(engine, graph_id: str, g: CSR) -> None:
     from repro.core.graph import CSRGraph
     rowptr, colidx, values = g
     engine.register_graph(graph_id, CSRGraph(rowptr, colidx, values,
                                              len(rowptr) - 1))
+
+
+def make_loop(config: Dict, traffic: Dict, seed: int, devices: Sequence):
+    """The loop of the traffic's kind, driving the configuration's model."""
+    model = loader.load("models", config["model"]["kind"])
+    return loader.load("loops", traffic["kind"]).Loop(
+        config, traffic, seed, devices, model)

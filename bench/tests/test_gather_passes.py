@@ -4,23 +4,23 @@ block gathers its rows once, so the reader gives 1.0; the parent's
 counters, which lack ``hbm_gather_passes``, give nothing."""
 import jax
 
-from bench import harness, system, traffic
+from bench import harness, loader, system
 from bench.tests.tiny import tiny_config
 
 PARENT_STATS = {"routed_hbm": 3, "batches_dispatched": 3}
 
 
 def read(run):
-    return harness.load_reader("gather_passes.full").read(run)
+    return loader.load("metrics", "gather_passes.full").read(run)
 
 
 def test_gather_passes_reads_one_on_a_tiny_hbm_run():
-    config = tiny_config()
-    config["model"] = {"widths": [16, 256, 40]}
+    config = tiny_config("gcn3-arxiv")
+    config["model"] = dict(config["model"], widths=[16, 256, 40])
     config["engine"] = {"backend": "hbm"}
-    loop = traffic.make_driver(config,
-                               system.load_json("traffic", "fullgraph-loop"),
-                               2**31 + 91, jax.devices()[:1])
+    loop = system.make_loop(config,
+                            loader.load_json("traffic", "fullgraph-loop"),
+                            2**31 + 91, jax.devices()[:1])
     try:
         loop.setup()
         stats0 = loop.engine.stats()

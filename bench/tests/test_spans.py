@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import harness
+from bench import harness, loader
 from bench.spans import OUTSIDE, PLAN_BUILD, PROGRAM_SPANS, idle_split
 from bench.trace import (HOST_PLANE, OPS_LINE, Event, TraceSummary,
                          load_events)
@@ -76,7 +76,7 @@ def run_of(evs=None, stats0=STATS0, stats1=STATS1):
 
 
 def read(name, run):
-    return harness.load_reader(name).read(run)
+    return loader.load("metrics", name).read(run)
 
 
 def test_idle_split_names_each_idle_stretch_by_its_innermost_span():

@@ -7,7 +7,7 @@ import sys
 
 import pytest
 
-from bench import harness, system
+from bench import harness, loader
 from bench.tests.conftest import ROOT
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -44,18 +44,41 @@ def test_per_layer_moves_a_metric_its_cells_report():
 @pytest.mark.parametrize("metric", SPEC["per_layer"],
                          ids=lambda m: m["name"])
 def test_reader_file_agrees_with_the_spec(metric):
-    reader = harness.load_reader(metric["name"])
+    reader = loader.load("metrics", metric["name"])
     assert reader.UNIT == metric["unit"] and reader.MOVES == metric["moves"]
 
 
+def test_device_trace_end_to_end_metrics_have_a_reader():
+    """An end-to-end metric that the loop cannot clock, one from the device
+    trace, is read by its own file."""
+    for m in SPEC["end_to_end"]:
+        if m["source"] == "device_trace":
+            assert loader.load("metrics", m["name"]).UNIT == m["unit"]
+
+
+MODEL_API = ("prepare", "make_inputs", "forward", "work", "reference_pairs",
+             "tiny")
+
+
 def test_cells_find_their_files():
+    """Each cell's configuration, traffic and limits, and the model kind,
+    graph kind and traffic kind they name, each resolve to a file."""
     for w in SPEC["workloads"]:
-        config = system.load_json("configs", w["config"])
+        config = loader.load_json("configs", w["config"])
         assert config["name"] == w["config"]
-        assert system.load_json("traffic", w["traffic"])["kind"]
-        assert (ROOT / "bench" / "limits" / f"{w['name']}.json").exists()
+        model = loader.load("models", config["model"]["kind"])
+        assert all(callable(getattr(model, f, None)) for f in MODEL_API)
+        assert callable(loader.load("data", config["graph"]["kind"]).build)
+        kind = loader.load_json("traffic", w["traffic"])["kind"]
+        assert isinstance(loader.load("loops", kind).Loop, type)
+        assert loader.load_json("limits", w["name"])["limits"]
     for c in SPEC["configs"]:
         assert (ROOT / c["file"]).exists()
+
+
+def test_a_missing_part_names_its_file():
+    with pytest.raises(FileNotFoundError, match="no models file for 'gat'"):
+        loader.load("models", "gat")
 
 
 def test_no_tpu_exits_nonzero_and_prints_no_result():

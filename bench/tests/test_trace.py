@@ -3,6 +3,7 @@ from pathlib import Path
 
 import pytest
 
+from bench import harness, loader
 from bench.trace import (HOST_PLANE, MODULES_LINE, OPS_LINE, Event,
                          NoMatchingEvents, TraceSummary, load_events)
 
@@ -70,3 +71,21 @@ def test_recorded_forward_pass():
     gaps = s.idle_gaps(3)
     assert gaps[0] == ["$_arraypad_impl.py:86 _pad_simple",
                        pytest.approx(0.04908986)]
+
+
+def test_forward_device_ms_is_busy_time_per_pass():
+    """``forward_device_ms`` on the recorded pass: its device busy time; and
+    nothing without a trace."""
+    s = TraceSummary(load_events(str(RECORDED / "arxiv_forward_trace.json.gz")))
+    reader = loader.load("metrics", "forward_device_ms")
+    assert reader.read(harness.Run(trace=s, units=1)) == \
+        pytest.approx(471.375075)
+    assert reader.read(harness.Run(trace=s, units=2)) == \
+        pytest.approx(235.6875375)
+    assert reader.read(harness.Run(trace=None, units=1)) is None
+
+
+def test_forward_ms_wall_is_the_loops_pass_time():
+    reader = loader.load("metrics", "forward_ms.wall")
+    assert reader.read(harness.Run(end_to_end={"forward_ms": 470.5})) == 470.5
+    assert reader.read(harness.Run(end_to_end={})) is None

@@ -2,13 +2,14 @@
 import numpy as np
 import pytest
 
-from bench.data import graphs as data
+from bench import loader
+from bench.data import csr_from_edges
 from bench.work import compulsory_s, dense_work, load_peaks, spmm_work
 
 
 def tiny_graph():
     # 3 nodes, edges 0->1, 1->2, 2->0, 2->1; + self loops after normalisation
-    return data.gcn_normalize(data.csr_from_edges(
+    return loader.load("models", "gcn").prepare(csr_from_edges(
         np.array([0, 1, 2, 2]), np.array([1, 2, 0, 1]), 3))
 
 

@@ -1,21 +1,28 @@
-"""A small stand-in for the benchmark's configuration, so that a whole run
-fits a CPU test: the same file with fewer nodes and narrower features."""
+"""Small stand-ins for the benchmark's configurations, so that a whole run
+fits a CPU test: each cell's configuration at the size its model file's
+``tiny()`` gives."""
 import time
+from typing import Dict
 
 import jax
 
-from bench import system
-from bench.harness import run_cell
+from bench import loader
+from bench.harness import cell_of, load_spec, run_cell
 
 
-def tiny_config():
-    cfg = system.load_json("configs", "gcn3-arxiv")
-    cfg["graph"] = dict(cfg["graph"], nodes=3000, edges=20000, max_degree=300)
-    cfg["model"] = {"widths": [16, 32, 8]}
-    return cfg
+def tiny_config(name: str) -> Dict:
+    config = loader.load_json("configs", name)
+    return loader.load("models", config["model"]["kind"]).tiny(config)
+
+
+def tiny_cell(workload: str):
+    """The cell's tiny configuration and its traffic mix."""
+    cell = cell_of(load_spec(), workload)
+    return (tiny_config(cell["config"]),
+            loader.load_json("traffic", cell["traffic"]))
 
 
 def run_tiny(workload: str, seed: int, seconds: float = 0.5):
     return run_cell(workload, seed, seconds, False,
                     t_start=time.perf_counter(), devices=jax.devices()[:1],
-                    config=tiny_config())
+                    config=tiny_cell(workload)[0])
