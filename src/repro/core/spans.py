@@ -10,10 +10,11 @@ them (scheduler, engine, plan cache).
 
 The dispatch phases run in this order and partition the time the engine
 counts in ``total_serve_s`` (``PREPARE`` also covers the feature concat
-inside ``spmm_batched``); ``ANSWER`` follows it::
+inside ``spmm_batched``; ``SCORES`` runs only in a dispatch that carries
+attention requests); ``ANSWER`` follows it::
 
     gcn.dispatch
-      prepare -> merge -> upload -> launch -> wait | answer
+      prepare -> merge -> upload -> scores -> launch -> wait | answer
 """
 from __future__ import annotations
 
@@ -28,12 +29,13 @@ DISPATCH = "gcn.dispatch"          # one fused engine dispatch, end to end
 PREPARE = "gcn.dispatch.prepare"   # cast, concat, bucket-pad the features
 MERGE = "gcn.dispatch.merge"       # host merge of the plans' slabs
 UPLOAD = "gcn.dispatch.upload"     # merged slabs host -> device
+SCORES = "gcn.dispatch.scores"     # launch of per-call attention values
 LAUNCH = "gcn.dispatch.launch"     # route + asynchronous kernel call
 WAIT = "gcn.dispatch.wait"         # host blocks until the outputs are ready
 ANSWER = "gcn.dispatch.answer"     # un-permute and slice the answers
 PLAN_BUILD = "gcn.plan.build"      # one partition-plan build (not a load)
 
-DISPATCH_PHASES = (PREPARE, MERGE, UPLOAD, LAUNCH, WAIT, ANSWER)
+DISPATCH_PHASES = (PREPARE, MERGE, UPLOAD, SCORES, LAUNCH, WAIT, ANSWER)
 
 
 @contextlib.contextmanager

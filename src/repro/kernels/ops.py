@@ -76,8 +76,12 @@ def spmm_auto(slabs, x, n_rows, *, return_decision=False):
 @functools.partial(jax.jit, static_argnames=("n_rows", "block_chunk"))
 def spmm_blocked(colidx, values, rowloc, out_row, x, n_rows, block_chunk: int = 1024):
     """jnp twin of the Pallas kernel: identical slab math, chunked over blocks
-    to bound the gathered-intermediate footprint (the VMEM analogue)."""
+    to bound the gathered-intermediate footprint (the VMEM analogue).
+    ``values`` ``[B, H, C]`` weighs the features as H heads of ``F / H``
+    columns, one set of slot values per head; ``[B, C]`` is one head."""
     B, C = colidx.shape
+    values = values.reshape(B, -1, C)
+    H = values.shape[1]
     R = out_row.shape[1]
     F = x.shape[1]
     bc = min(block_chunk, B) if B else 1
@@ -88,12 +92,15 @@ def spmm_blocked(colidx, values, rowloc, out_row, x, n_rows, block_chunk: int = 
         return jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1), constant_values=fill)
 
     ci = padded(colidx, 0).reshape(-1, bc, C)
-    va = padded(values, 0).reshape(-1, bc, C)
+    va = padded(values, 0).reshape(-1, bc, H, C)
     rl = padded(rowloc, R - 1).reshape(-1, bc, C)
 
     def chunk_fn(args):
         ci_c, va_c, rl_c = args
-        gathered = va_c[..., None].astype(jnp.float32) * x[ci_c].astype(jnp.float32)
+        # head h weighs its F / H columns
+        xg = x[ci_c].astype(jnp.float32).reshape(bc, C, H, F // H)
+        gathered = (jnp.moveaxis(va_c, 1, 2)[..., None].astype(jnp.float32)
+                    * xg).reshape(bc, C, F)
         onehot = jax.nn.one_hot(rl_c, R, dtype=jnp.float32)
         # HIGHEST: the TPU's default f32 matmul rounds operands to bf16
         return jnp.einsum("bcr,bcf->brf", onehot, gathered,

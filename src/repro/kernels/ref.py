@@ -9,7 +9,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["csr_spmm_ref", "slab_spmm_ref", "grouped_matmul_ref"]
+__all__ = ["csr_spmm_ref", "attention_spmm_ref", "slab_spmm_ref",
+           "grouped_matmul_ref"]
 
 
 def csr_spmm_ref(rowptr: np.ndarray, colidx: np.ndarray, values: np.ndarray,
@@ -26,6 +27,31 @@ def csr_spmm_ref(rowptr: np.ndarray, colidx: np.ndarray, values: np.ndarray,
     out = jax.ops.segment_sum(contrib, jnp.asarray(row_of), num_segments=n,
                               indices_are_sorted=True)   # CSR row order
     return out
+
+
+def attention_spmm_ref(rowptr: np.ndarray, colidx: np.ndarray, z: jax.Array,
+                       el: jax.Array, er: jax.Array,
+                       negative_slope: float = 0.2) -> jax.Array:
+    """Graph attention oracle, per head h over row i's nonzeros j:
+    ``out[i, h] = sum_j softmax_j(LeakyReLU(el[j, h] + er[i, h])) z[j, h]``.
+
+    z: ``[n_cols, H, F]``; el: ``[n_cols, H]``; er: ``[n_rows, H]``. COO
+    expansion with ``segment_max``, ``exp`` and ``segment_sum``, in f32.
+    """
+    n = len(rowptr) - 1
+    row_of = jnp.asarray(np.repeat(np.arange(n), np.diff(rowptr)))
+    cols = jnp.asarray(colidx)
+    z, el, er = (jnp.asarray(a, jnp.float32) for a in (z, el, er))
+    e = el[cols] + er[row_of]                                   # [nnz, H]
+    e = jnp.where(e >= 0, e, negative_slope * e)
+    m = jax.ops.segment_max(e, row_of, num_segments=n,
+                            indices_are_sorted=True)
+    p = jnp.exp(e - m[row_of])
+    s = jax.ops.segment_sum(p, row_of, num_segments=n,
+                            indices_are_sorted=True)
+    alpha = p / s[row_of]
+    return jax.ops.segment_sum(alpha[:, :, None] * z[cols], row_of,
+                               num_segments=n, indices_are_sorted=True)
 
 
 def slab_spmm_ref(colidx: jax.Array, values: jax.Array, rowloc: jax.Array,

@@ -1,6 +1,7 @@
 """Accel-GCN SpMM as a Pallas TPU kernel.
 
-TPU mapping of the paper's design (DESIGN.md §2):
+TPU mapping of the paper's design (the TPU pattern mode of
+``core/partition.py``):
 
 * one grid step == one *block* of the block-level partition: a fixed-capacity
   slab of ``C = deg_bound`` non-zeros covering up to ``R`` contiguous
@@ -17,8 +18,18 @@ TPU mapping of the paper's design (DESIGN.md §2):
 
 Slab metadata enters each grid step as ``[1, 1, C]`` blocks of the
 ``[B, 1, C]`` view of the ``[B, C]`` slabs (a ``(1, C)`` block of a
-``[B, C]`` array breaks the TPU's (8, 128) block-tiling rule). ``colidx``
-goes to SMEM, one block per step, and is read as scalars: the row gather is
+``[B, C]`` array breaks the TPU's (8, 128) block-tiling rule).
+
+The values may carry H heads, ``[B, H, C]``: one set of slot values per
+head, computed per call (``edge_softmax.py``), over features laid out as H
+heads of ``F_pad / H`` columns each, a whole number of 128-lane tiles. Lane
+tile ``t`` is reduced with head ``t // (F_pad / 128 / H)``. The values'
+view is then ``[B * H, 1, C]``: the resident and windowed kernels take
+their tile's head as a ``[1, 1, C]`` block, the HBM kernel all H of its
+block as ``[H, 1, C]``. With H = 1 the values are ``[B, C]``, every
+tile's head is 0, and every kernel computes what it does without heads.
+
+``colidx`` goes to SMEM, one block per step, and is read as scalars: the row gather is
 a loop of scalar-addressed row reads into a ``[C, F_tile]`` VMEM scratch (a
 vector-indexed gather ``x_ref[cols, :]`` does not lower on the TPU). The
 whole ``colidx`` array is never scalar-prefetched: at full size it is far
@@ -83,7 +94,7 @@ def scatter_block_rows(out_slabs: jax.Array, out_row: jax.Array,
     block rows -> global [n_rows, n_features]. Non-split blocks write
     disjoint rows; split-row blocks accumulate; slot n_rows is the padding
     sentinel and is dropped (sequential-grid revisit accumulation is the
-    real-TPU alternative; see DESIGN.md §2).
+    real-TPU alternative).
 
     Blocks fold in order, ``chunk`` at a time (the largest power of two up
     to ``SCATTER_CHUNK`` that divides B), by a loop of small scatter-adds:
@@ -104,33 +115,47 @@ def scatter_block_rows(out_slabs: jax.Array, out_row: jax.Array,
     return acc[:n_rows, :n_features]
 
 
-def slab_meta_specs(C: int, index_map):
-    """BlockSpecs of the ``[B, 1, C]`` colidx / values / rowloc views: one
-    block per grid step; colidx in SMEM (scalar row addresses), values and
-    rowloc in VMEM (vector operands of the weighted one-hot)."""
+def slab_meta_specs(C: int, index_map, values_map=None, heads: int = 1):
+    """BlockSpecs of the ``[B, 1, C]`` colidx / rowloc views and the
+    ``[B * H, 1, C]`` values view: one block per grid step; colidx in SMEM
+    (scalar row addresses), values and rowloc in VMEM (vector operands of
+    the weighted one-hot). ``values_map`` (default ``index_map``) places the
+    ``[heads, 1, C]`` values block in units of ``heads`` rows."""
     return [
         pl.BlockSpec((1, 1, C), index_map, memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1, C), index_map),
+        pl.BlockSpec((heads, 1, C), values_map or index_map),
         pl.BlockSpec((1, 1, C), index_map),
     ]
 
 
 def slab_meta_views(colidx, values, rowloc):
-    """``[B, C]`` slab metadata -> the ``[B, 1, C]`` views the kernels take."""
+    """``[B, C]`` slab metadata and ``[B, C]`` or ``[B, H, C]`` values ->
+    the ``[B, 1, C]`` and ``[B * H, 1, C]`` views the kernels take."""
     B, C = colidx.shape
-    return (colidx.reshape(B, 1, C), values.reshape(B, 1, C),
+    return (colidx.reshape(B, 1, C), values.reshape(-1, 1, C),
             rowloc.reshape(B, 1, C))
 
 
+def value_heads(values, f_lanes: int) -> tuple[int, int]:
+    """``(H, tiles_per_head)`` of ``values`` over ``f_lanes`` 128-lane tiles
+    of features: every head owns a whole number of tiles."""
+    H = values.shape[1] if values.ndim == 3 else 1
+    if f_lanes % H:
+        raise ValueError(f"{f_lanes} feature tiles do not split into "
+                         f"{H} heads of whole tiles")
+    return H, f_lanes // H
+
+
 def reduce_slab(values_ref, rowloc_ref, gathered: jax.Array,
-                R: int) -> jax.Array:
+                R: int, head=0) -> jax.Array:
     """Intra-block segment reduction ``[R, C] @ [C, F_tile]`` on the MXU.
 
-    The one-hot row map carries each slot's edge value, so padding slots
-    (value 0) contribute nothing. HIGHEST precision keeps the f32 products
-    exact on the MXU's multi-pass path.
+    The one-hot row map carries each slot's value for ``head`` (row
+    ``head`` of the ``[H, 1, C]`` values block), so padding slots (value 0)
+    contribute nothing. HIGHEST precision keeps the f32 products exact on
+    the MXU's multi-pass path.
     """
-    vals = values_ref[0].astype(jnp.float32)              # [1, C]
+    vals = values_ref[head].astype(jnp.float32)           # [1, C]
     rloc = rowloc_ref[0]                                  # [1, C]
     C = vals.shape[1]
     weights = jnp.where(
@@ -146,8 +171,8 @@ def _spmm_kernel(colidx_ref, values_ref, rowloc_ref, x_ref, out_ref,
                  gathered, *, C, R):
     """One block x one feature tile.
 
-    colidx_ref: int32[1, 1, C] SMEM; values_ref: f32[1, 1, C];
-    rowloc_ref: int32[1, 1, C]; x_ref: [N_pad, F_tile] feature tile (VMEM
+    colidx_ref: int32[1, 1, C] SMEM; values_ref: f32[1, 1, C] (the tile's
+    head); rowloc_ref: int32[1, 1, C]; x_ref: [N_pad, F_tile] feature tile (VMEM
     resident); out_ref: [1, R, F_tile]; gathered: f32[C, F_tile] scratch.
     """
     # Gather C dense rows from the feature tile: one scalar-addressed,
@@ -183,21 +208,24 @@ def _spmm_block_slabs(colidx, values, rowloc, out_row, x, n_rows, *,
     N_pad = pad_rows(N)
     x_p = jnp.zeros((N_pad, F_pad), x.dtype).at[:N, :F].set(x)
     nf = F_pad // f_tile
+    H, tph = value_heads(values, nf)
 
     if grid_order == "block_major":
         grid = (B, nf)
         block_ix = lambda b, j: (b, 0, 0)       # noqa: E731
+        head_ix = lambda b, j: (b * H + j // tph, 0, 0)  # noqa: E731
         x_ix = lambda b, j: (0, j)              # noqa: E731
         out_ix = lambda b, j: (b, 0, j)         # noqa: E731
     else:  # ft_major: (feature-tile, block) — block axis innermost
         grid = (nf, B)
         block_ix = lambda j, b: (b, 0, 0)       # noqa: E731
+        head_ix = lambda j, b: (b * H + j // tph, 0, 0)  # noqa: E731
         x_ix = lambda j, b: (0, j)              # noqa: E731
         out_ix = lambda j, b: (b, 0, j)         # noqa: E731
     out_slabs = pl.pallas_call(
         functools.partial(_spmm_kernel, C=C, R=R),
         grid=grid,
-        in_specs=slab_meta_specs(C, block_ix) + [
+        in_specs=slab_meta_specs(C, block_ix, head_ix) + [
             pl.BlockSpec((N_pad, f_tile), x_ix),
         ],
         out_specs=pl.BlockSpec((1, R, f_tile), out_ix),
@@ -211,7 +239,7 @@ def _spmm_block_slabs(colidx, values, rowloc, out_row, x, n_rows, *,
 
 def spmm_block_slabs(
     colidx: jax.Array,   # int32[B, C]
-    values: jax.Array,   # f32[B, C]
+    values: jax.Array,   # f32[B, C], or f32[B, H, C] for H heads
     rowloc: jax.Array,   # int32[B, C]
     out_row: jax.Array,  # int32[B, R]
     x: jax.Array,        # [N, F]
@@ -302,12 +330,14 @@ def _spmm_block_slabs_windowed(colidx, values, rowloc, out_row, x, n_rows, *,
     N_pad = num_windows * window
     x_p = jnp.zeros((N_pad, F_pad), x.dtype).at[:N, :F].set(x)
     nf = F_pad // f_tile
+    H, tph = value_heads(values, nf)
 
     grid = (B, nf, num_windows)  # window axis innermost: consecutive
     out_slabs = pl.pallas_call(  # revisits of one output block accumulate
         functools.partial(_spmm_kernel_windowed, C=C, R=R, window=window),
         grid=grid,
-        in_specs=slab_meta_specs(C, lambda b, j, w: (b, 0, 0)) + [
+        in_specs=slab_meta_specs(C, lambda b, j, w: (b, 0, 0),
+                                 lambda b, j, w: (b * H + j // tph, 0, 0)) + [
             pl.BlockSpec((window, f_tile), lambda b, j, w: (w, j)),
         ],
         out_specs=pl.BlockSpec((1, R, f_tile), lambda b, j, w: (b, 0, j)),
@@ -321,7 +351,7 @@ def _spmm_block_slabs_windowed(colidx, values, rowloc, out_row, x, n_rows, *,
 
 def spmm_block_slabs_windowed(
     colidx: jax.Array,   # int32[B, C]
-    values: jax.Array,   # f32[B, C]
+    values: jax.Array,   # f32[B, C], or f32[B, H, C] for H heads
     rowloc: jax.Array,   # int32[B, C]
     out_row: jax.Array,  # int32[B, R]
     x: jax.Array,        # [N, F]

@@ -25,21 +25,50 @@ runs (fp32 reduction order within a block is unchanged).
 
 ``pad_blocks_to`` rounds the merged block count up to a bucket so repeated
 batches with different graph mixes reuse one compiled kernel.
+
+A dispatch may carry H value heads per graph (:class:`HeadValues`): each
+graph's features are then H heads of ``F / H`` columns, and each head's
+slot values are computed per call by ``edge_softmax.py`` from the merged
+structure, after the upload, as the ``scores`` phase. The per-call inputs
+merge like the structure: each graph's per-block live slot counts
+(``nnz``), head kinds and negative slopes are concatenated and padded with
+the blocks, ``el`` is concatenated along the feature rows and ``er`` along
+the output rows.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.spans import LAUNCH, MERGE, PREPARE, UPLOAD, span
+from ..core.spans import LAUNCH, MERGE, PREPARE, SCORES, UPLOAD, span
+from .edge_softmax import NONE, edge_softmax_slabs
 from .router import RoutingDecision, route_spmm
 from .spmm_accel import spmm_block_slabs, spmm_block_slabs_windowed
 from .spmm_hbm import spmm_block_slabs_hbm
 
-__all__ = ["batch_graph_slabs", "spmm_batched", "bucket_blocks"]
+__all__ = ["batch_graph_slabs", "spmm_batched", "bucket_blocks",
+           "HeadValues"]
+
+
+@dataclasses.dataclass(frozen=True)
+class HeadValues:
+    """Where one graph's H value heads come from in a dispatch.
+
+    ``kind[h]`` is ``edge_softmax.ATTENTION`` (computed per call from
+    ``el``, ``er`` and ``slope[h]``), ``PLAIN`` (the plan's own values) or
+    ``NONE`` (zeros). ``el`` is ``[n_cols, H]`` and ``er`` ``[n_rows, H]``
+    in the plan's degree-sorted row order (``edge_softmax.to_plan_order``),
+    zero in the heads that are not attention.
+    """
+
+    kind: np.ndarray      # int32[H]
+    slope: np.ndarray     # float32[H]
+    el: jax.Array
+    er: jax.Array
 
 
 def bucket_blocks(b_total: int, min_bucket: int = 8) -> int:
@@ -66,7 +95,8 @@ def batch_graph_slabs(
 
     Returns ``(merged, out_offsets, col_offsets, n_out_total)`` where
     ``merged`` has the same keys as a single-graph slab dict (colidx, values,
-    rowloc, out_row, R, C) and graph ``i``'s output rows live at
+    rowloc, out_row, R, C, and ``nnz``, live slots per block, where every
+    graph's dict has it) and graph ``i``'s output rows live at
     ``[out_offsets[i], out_offsets[i] + n_rows_list[i])`` of the batched
     result. Host-side numpy; cost is O(sum B_g * C) copies, far below a
     partition rebuild.
@@ -121,7 +151,26 @@ def batch_graph_slabs(
 
     merged = {"colidx": colidx, "values": values, "rowloc": rowloc,
               "out_row": out_row, "R": R, "C": C}
+    if all("nnz" in s for s in slab_list):
+        nnz = np.concatenate([np.asarray(s["nnz"], np.int32)
+                              for s in slab_list])
+        merged["nnz"] = np.pad(nnz, (0, colidx.shape[0] - len(nnz)))
     return merged, out_offsets, col_offsets, n_out
+
+
+def _merge_heads(heads: Sequence[HeadValues], blocks: Sequence[int],
+                 b_total: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Per-block head kinds and slopes ``[b_total, H]``: each graph's rows
+    repeated over its blocks, bucket-padding blocks ``NONE``."""
+    H = len(heads[0].kind)
+    kind = np.full((b_total, H), NONE, np.int32)
+    slope = np.zeros((b_total, H), np.float32)
+    start = 0
+    for hv, b in zip(heads, blocks):
+        kind[start:start + b] = hv.kind
+        slope[start:start + b] = hv.slope
+        start += b
+    return kind, slope
 
 
 _PALLAS_KERNELS = {
@@ -141,6 +190,7 @@ def spmm_batched(
     return_decision: bool = False,
     grid_order: str = "block_major",
     phases: Optional[Dict[str, float]] = None,
+    heads: Optional[Sequence[HeadValues]] = None,
 ) -> List[jax.Array] | Tuple[List[jax.Array], Optional[RoutingDecision]]:
     """Fused SpMM over several graphs; returns one ``[n_rows_g, F_g]`` output
     per graph (degree-sorted row order, same as the single-graph kernel).
@@ -160,11 +210,19 @@ def spmm_batched(
     :func:`repro.kernels.spmm_accel.spmm_block_slabs`); dispatches that
     route to the windowed/HBM kernels ignore it.
 
+    ``heads`` (one :class:`HeadValues` per graph, all with the same H;
+    each slab dict then carries ``nnz``) makes the dispatch multi-head:
+    every ``x`` is H heads of ``F / H`` columns (a whole number of 128-lane
+    tiles for the Pallas kernels) and the values of each head come from its
+    kind.
+
     The body runs as the ``gcn.dispatch`` phases ``merge`` (host slab
     merge), ``prepare`` (feature concat), ``upload`` (the four merged slab
-    arrays to the device) and ``launch`` (route and the asynchronous kernel
-    call), each a span of :mod:`repro.core.spans`; ``phases``, if given, is
-    the caller's dict that gains each phase's seconds.
+    arrays to the device), ``scores`` (with ``heads``: the asynchronous
+    launch of the per-call values) and ``launch`` (route and the
+    asynchronous kernel call), each a span of :mod:`repro.core.spans`;
+    ``phases``, if given, is the caller's dict that gains each phase's
+    seconds.
     """
     G = len(slab_list)
     assert G == len(x_list) == len(n_rows_list) and G > 0
@@ -179,6 +237,10 @@ def spmm_batched(
         merged, out_off, _, n_out = batch_graph_slabs(
             slab_list, list(n_rows_list), n_cols_list,
             pad_blocks_to=pad_blocks_to)
+        if heads is not None:
+            kind, slope = _merge_heads(
+                heads, [int(np.shape(s["colidx"])[0]) for s in slab_list],
+                merged["colidx"].shape[0])
 
     with span(PREPARE, phases):
         x_cat = jnp.concatenate(
@@ -186,10 +248,23 @@ def spmm_batched(
                      ((0, 0), (0, F - f))) if f < F
              else jnp.asarray(x, dtype=jnp.float32)
              for x, f in zip(x_list, f_list)], axis=0)
+        if heads is not None:
+            el = jnp.concatenate([jnp.asarray(h.el, jnp.float32)
+                                  for h in heads])
+            er = jnp.concatenate([jnp.asarray(h.er, jnp.float32)
+                                  for h in heads]
+                                 + [jnp.zeros((1, kind.shape[1]),
+                                              jnp.float32)])
 
     with span(UPLOAD, phases):
         slabs = [jnp.asarray(merged[k])
                  for k in ("colidx", "values", "rowloc", "out_row")]
+        if heads is not None:
+            per_call = [jnp.asarray(a) for a in (merged["nnz"], kind, slope)]
+
+    if heads is not None:
+        with span(SCORES, phases):
+            slabs[1] = edge_softmax_slabs(*slabs, *per_call, el, er)
 
     decision: Optional[RoutingDecision] = None
     with span(LAUNCH, phases):

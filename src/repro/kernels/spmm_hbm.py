@@ -34,12 +34,18 @@ Per grid step (C=256, R=64 defaults, f32; W = 256 at F = 256):
                                             cluster)
   out slab               [R, W]           R*W*4: 64 KiB at W=256 (x2
                                             pipeline buffers)
-  values/rowloc          2 x [C]            2 KiB  (x2 pipeline buffers)
+  values                 [H, 1, C]          H KiB  (x2 pipeline buffers)
+  rowloc                 [C]                1 KiB  (x2 pipeline buffers)
   colidx (SMEM)          [C]                1 KiB  (x2 pipeline buffers)
   weighted one-hot       [R, C]            64 KiB
 
 ``colidx`` arrives in SMEM one block per grid step, so each DMA address is
 a scalar read (a DMA address cannot come from a VMEM vector element).
+
+With H heads of values (``spmm_accel`` module docstring) a step takes all H
+rows of its block's values and reduces lane tile ``t`` of plane ``j`` with
+head ``(j * W / 128 + t) // (F_pad / 128 / H)``: each row is still gathered
+once per plane, whatever the number of heads.
 
 Batched multi-graph slabs (``spmm_batched`` merge) run unchanged: column
 indices arrive pre-shifted into the concatenated feature rows, padded slab
@@ -63,15 +69,16 @@ from .platform import pallas_interpret
 from .router import hbm_gather_width, pad_features, pad_rows
 from .spmm_accel import (
     DEFAULT_F_TILE as LANES, reduce_slab, scatter_block_rows,
-    slab_meta_specs, slab_meta_views,
+    slab_meta_specs, slab_meta_views, value_heads,
 )
 
 DMA_DEPTH = 8   # one-row gather copies in flight per grid step
 
 
 def _kernel(colidx_ref, values_ref, rowloc_ref, x_hbm, out_ref,
-            gathered, sem, *, C, R):
-    """colidx_ref: int32[1, 1, C] SMEM; values/rowloc: [1, 1, C] VMEM;
+            gathered, sem, *, C, R, nf, tph):
+    """colidx_ref: int32[1, 1, C] SMEM; values: [H, 1, C] VMEM, the
+    block's H heads, ``tph`` lane tiles each; rowloc: [1, 1, C] VMEM;
     x_hbm: [nf, N_pad, L, 128] ANY (the padded features as ``nf = F_pad /
     W`` planes, each row ``L = W / 128`` lane tiles — ANY refs see the
     whole array, so each DMA copies one whole row of plane ``j``); out_ref:
@@ -82,7 +89,7 @@ def _kernel(colidx_ref, values_ref, rowloc_ref, x_hbm, out_ref,
     # Bucket-padding blocks carry all-zero values: skip their C-row DMA loop
     # (and never read the uninitialized gather scratch) — a padded dispatch
     # pays grid-step overhead for dead blocks, not HBM bandwidth.
-    live = jnp.any(values_ref[0] != 0.0)
+    live = jnp.any(values_ref[...] != 0.0)
 
     @pl.when(live)
     def _gather_and_reduce():
@@ -108,9 +115,16 @@ def _kernel(colidx_ref, values_ref, rowloc_ref, x_hbm, out_ref,
 
         jax.lax.fori_loop(0, C, issue, 0)
         jax.lax.fori_loop(max(C - DMA_DEPTH, 0), C, drain, 0)
-        for t in range(gathered.shape[0]):
+        L = gathered.shape[0]
+        for t in range(L):
+            # A static head where the plane cannot change it (one plane,
+            # or one head): H = 1 then compiles to the single-head kernel,
+            # with no traced sublane index into the values.
+            head = ((j * L + t) // tph if nf > 1 and tph < nf * L
+                    else t // tph)
             out_ref[0, :, t * LANES:(t + 1) * LANES] = reduce_slab(
-                values_ref, rowloc_ref, gathered[t].astype(jnp.float32), R)
+                values_ref, rowloc_ref, gathered[t].astype(jnp.float32), R,
+                head)
 
     @pl.when(jnp.logical_not(live))
     def _dead_block():
@@ -127,15 +141,16 @@ def _spmm_block_slabs_hbm(colidx, values, rowloc, out_row, x, n_rows, *,
     N_pad = pad_rows(N)
     W = hbm_gather_width(F_pad, C, jnp.dtype(x.dtype).itemsize)
     nf, L = F_pad // W, W // LANES
+    H, tph = value_heads(values, F_pad // LANES)
     # planes [nf, N_pad, L, 128] (module docstring); at nf == 1 the
     # transpose only moves a unit axis
     x_p = (jnp.zeros((N_pad, F_pad), x.dtype).at[:N, :F].set(x)
            .reshape(N_pad, nf, L, LANES).transpose(1, 0, 2, 3))
 
     out_slabs = pl.pallas_call(
-        functools.partial(_kernel, C=C, R=R),
+        functools.partial(_kernel, C=C, R=R, nf=nf, tph=tph),
         grid=(B, nf),
-        in_specs=slab_meta_specs(C, lambda b, j: (b, 0, 0)) + [
+        in_specs=slab_meta_specs(C, lambda b, j: (b, 0, 0), heads=H) + [
             pl.BlockSpec(memory_space=pl.ANY),      # X stays in HBM
         ],
         out_specs=pl.BlockSpec((1, R, W), lambda b, j: (b, 0, j)),
@@ -151,6 +166,7 @@ def _spmm_block_slabs_hbm(colidx, values, rowloc, out_row, x, n_rows, *,
 
 
 def spmm_block_slabs_hbm(colidx, values, rowloc, out_row, x, n_rows):
-    """HBM-gather SpMM over packed slabs; returns [n_rows, F] float32."""
+    """HBM-gather SpMM over packed slabs, ``values`` ``[B, C]`` or ``[B, H,
+    C]`` (H heads); returns [n_rows, F] float32."""
     return _spmm_block_slabs_hbm(colidx, values, rowloc, out_row, x, n_rows,
                                  interpret=pallas_interpret())

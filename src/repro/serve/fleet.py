@@ -95,6 +95,9 @@ class FleetGraphEngine(GraphServeEngine):
     bounds each device's plan-cache shard, so fleet plan capacity (and HBM
     residency) scales with device count — the ROADMAP's "serve more graphs
     than one host's HBM holds" axis.
+
+    Attention requests are refused at ``submit`` with a ``ValueError``: the
+    fleet's sharded and forwarded dispatch paths carry no per-call values.
     """
 
     def __init__(
@@ -185,6 +188,11 @@ class FleetGraphEngine(GraphServeEngine):
     def close(self) -> None:
         super().close()
         self._pool.shutdown(wait=True)
+
+    def _validate_attention(self, graph_id: str, x, attention,
+                            negative_slope: float):
+        raise ValueError(f"{type(self).__name__} does not serve attention "
+                         f"requests")
 
     # -------------------------------------------------------------- replicas
     def _add_replica(self, key, dev: int) -> bool:

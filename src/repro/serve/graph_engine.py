@@ -40,11 +40,18 @@ Tuning knobs:
 * ``max_pending`` — admission bound; full queue blocks submitters
   (backpressure) or raises with ``submit(..., block=False)``.
 
+A request may carry graph attention inputs (``submit(..., attention=(el,
+er))``, GAT): per head, slot values computed per call from per-row scores
+and softmaxed over each row's nonzeros (``kernels/edge_softmax.py``), then
+the same fused slab kernels with one set of values per head. Attention
+requests on one graph in one flush fuse as more heads, and the graph's plain
+requests beside them as one more head with the plan's own values.
+
 Per-request (enqueue->answer) latency comes from the scheduler's WorkItem
 clock. Each dispatch's host wall time, from its start through
 ``block_until_ready`` (feature prep, slab merge, upload, launch and the wait
 on the device, not the kernel alone), accumulates in ``total_serve_s``, and
-by phase in ``dispatch_{prepare,merge,upload,launch,wait}_s``;
+by phase in ``dispatch_{prepare,merge,upload,scores,launch,wait}_s``;
 ``dispatch_answer_s`` times the un-permute and slicing after it. Each phase
 is also a span on the profiler's clock (:mod:`repro.core.spans`).
 ``stats()`` merges engine counters, plan-cache counters (``cache_*``) and
@@ -54,6 +61,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import math
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -70,13 +78,14 @@ from ..core.plan_cache import (
 )
 from ..core import spans
 from ..core.plan_repair import EdgeDelta, delta_chain_hash, repair_plan
-from ..kernels.router import RoutingDecision
-from ..kernels.spmm_batched import bucket_blocks, spmm_batched
+from ..kernels.edge_softmax import ATTENTION, NONE, PLAIN, to_plan_order
+from ..kernels.router import RoutingDecision, pad_features
+from ..kernels.spmm_batched import HeadValues, bucket_blocks, spmm_batched
 from ..tuning.tuner import PlanTuner
 from ..tuning.search import TuningCandidate
 from .scheduler import BatchScheduler, ClassSpec, WorkItem
 
-__all__ = ["GraphRequest", "GraphServeEngine"]
+__all__ = ["Attention", "GraphRequest", "GraphServeEngine"]
 
 logger = logging.getLogger(__name__)
 
@@ -94,6 +103,19 @@ class GraphRequest:
     #                                    queue wait behind earlier dispatches)
     klass: str = "default"             # SLO class (must name a ClassSpec)
     tenant: Optional[str] = None       # opaque owner tag (stats only)
+
+
+@dataclasses.dataclass(frozen=True)
+class Attention:
+    """The attention inputs of one request, as ``submit`` validated them:
+    ``el [n_cols, heads]``, ``er [n_rows, heads]`` and features of
+    ``heads`` heads of ``f_head`` columns."""
+
+    el: jax.Array
+    er: jax.Array
+    negative_slope: float
+    heads: int
+    f_head: int
 
 
 class GraphServeEngine:
@@ -184,6 +206,8 @@ class GraphServeEngine:
         self.padded_blocks = 0       # blocks actually dispatched (bucketed)
         self.backend_dispatches: Dict[str, int] = {
             "resident": 0, "windowed": 0, "hbm": 0, "blocked": 0}
+        self.attn_dispatches = 0     # dispatches carrying attention heads
+        self.attn_heads = 0          # attention heads over those dispatches
         # row-gather passes each block of an hbm dispatch makes (F_pad / W,
         # router.hbm_gather_width), summed over hbm dispatches
         self.hbm_gather_passes = 0
@@ -356,6 +380,14 @@ class GraphServeEngine:
             pool.shutdown(wait=True)
 
     # ------------------------------------------------------------------ serve
+    def _registered(self, graph_id: str) -> CSRGraph:
+        with self._bind_lock:
+            g = self._graphs.get(graph_id)
+            if g is None:
+                raise KeyError(f"graph {graph_id!r} not registered "
+                               f"(known: {sorted(self._graphs)})")
+        return g
+
     def _validate(self, graph_id: str, x) -> None:
         """Cheap synchronous admission checks: registration + feature shape.
 
@@ -364,32 +396,71 @@ class GraphServeEngine:
         thread and plan resolution (which can mean an O(n) rebuild after an
         eviction) happens on the flush thread where it belongs.
         """
-        with self._bind_lock:
-            g = self._graphs.get(graph_id)
-            if g is None:
-                raise KeyError(f"graph {graph_id!r} not registered "
-                               f"(known: {sorted(self._graphs)})")
+        g = self._registered(graph_id)
         shape = tuple(getattr(x, "shape", ()))
         if len(shape) != 2 or shape[0] != g.n_cols:
             raise ValueError(
                 f"request for {graph_id!r} has features {shape}, "
                 f"expected [{g.n_cols}, F]")
 
+    def _validate_attention(self, graph_id: str, x, attention,
+                            negative_slope: float) -> Attention:
+        """Admission checks of an attention request: features ``[n_cols,
+        H, F_h]``, ``el [n_cols, H]``, ``er [n_rows, H]`` and a finite
+        negative slope."""
+        g = self._registered(graph_id)
+        shape = tuple(getattr(x, "shape", ()))
+        if len(shape) != 3:
+            raise ValueError(
+                f"attention request for {graph_id!r} has features {shape}, "
+                f"expected [{g.n_cols}, H, F_h]")
+        n_heads, f_head = shape[1], shape[2]
+        try:
+            el, er = attention
+        except (TypeError, ValueError):
+            raise ValueError("attention must be a pair (el, er)") from None
+        want = {"features": ((g.n_cols,), shape[:1]),
+                "el": ((g.n_cols, n_heads), tuple(getattr(el, "shape", ()))),
+                "er": ((g.n_rows, n_heads), tuple(getattr(er, "shape", ())))}
+        for name, (expected, got) in want.items():
+            if got != expected:
+                raise ValueError(f"attention request for {graph_id!r}: "
+                                 f"{name} shape {got}, expected {expected}")
+        if not math.isfinite(negative_slope):
+            raise ValueError(f"negative_slope must be finite, got "
+                             f"{negative_slope!r}")
+        return Attention(el, er, float(negative_slope), n_heads, f_head)
+
     def submit(self, graph_id: str, x: jax.Array, *,
+               attention: Optional[Tuple[jax.Array, jax.Array]] = None,
+               negative_slope: float = 0.2,
                block: bool = True, klass: str = "default",
                tenant: Optional[str] = None) -> Future:
         """Admit one request; returns a ``Future`` of the ``[n_rows, F]``
         aggregation in ORIGINAL row order.
 
-        Validation (unknown graph, wrong feature shape, unknown SLO class)
-        raises here, synchronously. A full admission queue blocks
-        (backpressure) or, with ``block=False``, raises
+        With ``attention=(el, er)`` the request is a graph attention
+        aggregation over ``H`` heads: ``x`` is ``z [n_cols, H, F_h]``,
+        ``el [n_cols, H]`` and
+        ``er [n_rows, H]``, and the answer, shaped like ``z``, is ``out[i,
+        h] = sum_j softmax_j(LeakyReLU(el[j, h] + er[i, h])) z[j, h]`` over
+        row i's nonzeros j, the LeakyReLU with ``negative_slope``.
+
+        Validation (unknown graph, wrong feature or score shape, unknown
+        SLO class, an engine that serves no attention) raises here,
+        synchronously. A full admission queue blocks (backpressure) or,
+        with ``block=False``, raises
         :class:`repro.serve.scheduler.QueueFullError`. ``klass`` names one
         of the engine's configured :class:`ClassSpec` entries; ``tenant``
         is an opaque owner tag carried into per-class stats.
         """
-        self._validate(graph_id, x)
-        return self.scheduler.submit((graph_id, x), block=block,
+        if attention is None:
+            self._validate(graph_id, x)
+            payload = (graph_id, x)
+        else:
+            payload = (graph_id, x, self._validate_attention(
+                graph_id, x, attention, negative_slope))
+        return self.scheduler.submit(payload, block=block,
                                      klass=klass, tenant=tenant).future
 
     def mutate(self, graph_id: str, delta: EdgeDelta, *,
@@ -471,17 +542,12 @@ class GraphServeEngine:
                        out: jax.Array, now: float
                        ) -> Tuple[List[Tuple[WorkItem, jax.Array]], float]:
         """Split a fused group's output back per request: feature columns
-        sliced by each item's width, plus the summed enqueue->now wait.
-        Shared by the local, sharded, and forwarded dispatch paths so the
-        fusion/latency semantics cannot diverge between them."""
-        answers: List[Tuple[WorkItem, jax.Array]] = []
-        col = 0
-        wait_s = 0.0
-        for item, w in zip(grp, widths):
-            answers.append((item, out[:, col:col + w]))
-            col += w
-            wait_s += now - item.t_enqueue
-        return answers, wait_s
+        sliced by each item's width (``_column_cuts``, as the local
+        dispatch cuts them), plus the summed enqueue->now wait. Used by
+        the fleet's sharded and forwarded dispatch paths."""
+        answers = [(item, cut(out))
+                   for item, cut in GraphServeEngine._column_cuts(grp, widths)]
+        return answers, sum(now - item.t_enqueue for item in grp)
 
     @staticmethod
     def _is_mutation(item: WorkItem) -> bool:
@@ -620,37 +686,30 @@ class GraphServeEngine:
         ``dispatch_<phase>_s`` counter."""
         t0 = time.perf_counter()
         phases: Dict[str, float] = {}
-        plans: List[PartitionPlan] = []
-        xs: List[jax.Array] = []
-        col_splits: List[List[int]] = []
+        plans = [plan for _gid, _grp, plan in batch]
+        heads: Optional[List[HeadValues]] = None
         with spans.span(spans.PREPARE, phases):
-            for _gid, grp, plan in batch:
-                feats = [jnp.asarray(it.payload[1], dtype=jnp.float32)
-                         for it in grp]
-                plans.append(plan)
-                x = (feats[0] if len(feats) == 1
-                     else jnp.concatenate(feats, axis=1))
-                if self.feature_bucket:
-                    w = int(x.shape[1])
-                    pad = bucket_blocks(w, 1) - w   # next power of two
-                    if pad:
-                        x = jnp.pad(x, ((0, 0), (0, pad)))
-                xs.append(x)
-                col_splits.append([int(f.shape[1]) for f in feats])
+            if any(self._attention_of(it) for _gid, grp, _plan in batch
+                   for it in grp):
+                xs, heads, cuts = self._prepare_heads(batch)
+            else:
+                xs, cuts = self._prepare_plain(batch)
 
         b_total = sum(p.num_blocks for p in plans)
         pad_to = None
         if self.block_bucket:
             pad_to = bucket_blocks(b_total, self.block_bucket)
         backend, grid_order = self._effective_launch(plans)
+        slabs = [p.slabs if heads is None
+                 else dict(p.slabs, nnz=p.partition.nnz_blk) for p in plans]
         outs, decision = spmm_batched(
-            [p.slabs for p in plans], xs, [p.n_rows for p in plans],
+            slabs, xs, [p.n_rows for p in plans],
             backend=backend, pad_blocks_to=pad_to, return_decision=True,
-            grid_order=grid_order, phases=phases)
+            grid_order=grid_order, phases=phases, heads=heads)
         with spans.span(spans.WAIT, phases):
             jax.block_until_ready(outs)
         # host wall time of this dispatch up to ready outputs: the phases
-        # prepare, merge, upload, launch and wait, back to back
+        # prepare, merge, upload, scores, launch and wait, back to back
         dt = time.perf_counter() - t0
 
         executed = decision.backend if decision is not None else "blocked"
@@ -662,6 +721,10 @@ class GraphServeEngine:
             self.last_decision = decision
             self.live_blocks += b_total
             self.padded_blocks += pad_to if pad_to else b_total
+            if heads is not None:
+                self.attn_dispatches += 1
+                self.attn_heads += sum(int(np.sum(h.kind == ATTENTION))
+                                       for h in heads)
         if logger.isEnabledFor(logging.DEBUG):
             logger.debug(
                 "dispatch: graphs=%d blocks=%d->%d backend=%s (%s) %.1fms",
@@ -676,15 +739,15 @@ class GraphServeEngine:
         n_req = n_rows = n_vals = 0
         wait_s = 0.0
         with spans.span(spans.ANSWER, phases):
-            for (_gid, grp, plan), out, widths in zip(batch, outs,
-                                                       col_splits):
+            for (_gid, grp, plan), out, cut in zip(batch, outs, cuts):
                 out = out[plan.inv_perm]      # back to original row order
-                sliced, wait = self._slice_answers(grp, widths, out, now)
-                answers.extend(sliced)
+                for item, answer in cut:
+                    result = answer(out)
+                    answers.append((item, result))
+                    n_vals += math.prod(result.shape)
+                    wait_s += now - item.t_enqueue
                 n_req += len(grp)
                 n_rows += plan.n_rows * len(grp)
-                n_vals += plan.n_rows * sum(widths)
-                wait_s += wait
         # only the increments sit under the lock (concurrent fleet device
         # launches must not serialize their un-permute/slice work on it)
         with self._counters_lock:
@@ -701,8 +764,107 @@ class GraphServeEngine:
             item.complete(result)
         # autotuning LAST: every live answer above already resolved, so
         # shadow work can never sit between a request and its result
-        if self.tuner is not None:
+        if self.tuner is not None and heads is None:
             self._tuner_tick(batch, xs, dt)
+
+    @staticmethod
+    def _attention_of(item: WorkItem) -> Optional[Attention]:
+        p = item.payload
+        return p[2] if len(p) > 2 and isinstance(p[2], Attention) else None
+
+    def _prepare_plain(self, batch):
+        """Features of a dispatch of plain requests: each graph's requests
+        side by side, the width bucketed; per item, the cut of its answer
+        from the graph's (original row order)."""
+        xs, cuts = [], []
+        for _gid, grp, _plan in batch:
+            feats = [jnp.asarray(it.payload[1], dtype=jnp.float32)
+                     for it in grp]
+            x = (feats[0] if len(feats) == 1
+                 else jnp.concatenate(feats, axis=1))
+            if self.feature_bucket:
+                w = int(x.shape[1])
+                pad = bucket_blocks(w, 1) - w   # next power of two
+                if pad:
+                    x = jnp.pad(x, ((0, 0), (0, pad)))
+            xs.append(x)
+            cuts.append(self._column_cuts(
+                grp, [int(f.shape[1]) for f in feats]))
+        return xs, cuts
+
+    @staticmethod
+    def _column_cuts(items, widths, col: int = 0):
+        """The cuts of requests whose features, ``widths`` wide, sit side
+        by side from column ``col`` of their graph's answer."""
+        cut = []
+        for it, w in zip(items, widths):
+            cut.append((it, lambda out, c=col, w=w: out[:, c:c + w]))
+            col += w
+        return cut
+
+    def _prepare_heads(self, batch):
+        """Features of a dispatch that carries attention requests: every
+        graph's become H heads of ``fhp`` columns, H the most heads a graph
+        of the dispatch needs and ``fhp`` its widest head padded to whole
+        128-lane tiles. A graph's heads are its attention requests' heads in
+        arrival order, then one head of its plain requests side by side
+        (the plan's own values), then zero heads. Returns the features, the
+        :class:`HeadValues` and the cuts of ``_prepare_plain``."""
+        split = [([it for it in grp if self._attention_of(it)],
+                  [it for it in grp if not self._attention_of(it)])
+                 for _gid, grp, _plan in batch]
+        widths = [self._attention_of(it).f_head
+                  for att, _ in split for it in att]
+        widths += [sum(int(it.payload[1].shape[1]) for it in plain)
+                   for _, plain in split if plain]
+        fhp = max(pad_features(w, 128) for w in widths)
+        H = max(sum(self._attention_of(it).heads for it in att) + bool(plain)
+                for att, plain in split)
+        xs, heads, cuts = [], [], []
+        for (_gid, _grp, plan), (att, plain) in zip(batch, split):
+            n = plan.n_cols
+            cols, els, ers, kind, slope, cut = [], [], [], [], [], []
+            for it in att:
+                a = self._attention_of(it)
+                z = jnp.asarray(it.payload[1], jnp.float32)
+                cols.append(jnp.pad(z, ((0, 0), (0, 0), (0, fhp - a.f_head))
+                                    ).reshape(n, a.heads * fhp))
+                els.append(jnp.asarray(a.el, jnp.float32))
+                ers.append(jnp.asarray(a.er, jnp.float32))
+                cut.append((it, self._head_cut(len(kind), a, H, fhp)))
+                kind += [ATTENTION] * a.heads
+                slope += [a.negative_slope] * a.heads
+            rest = H - len(kind)           # heads with no scores
+            if plain:
+                feats = [jnp.asarray(it.payload[1], jnp.float32)
+                         for it in plain]
+                x = jnp.concatenate(feats, axis=1)
+                cols.append(jnp.pad(x, ((0, 0), (0, fhp - x.shape[1]))))
+                cut += self._column_cuts(
+                    plain, [int(f.shape[1]) for f in feats],
+                    len(kind) * fhp)
+                kind.append(PLAIN)
+            if len(kind) < H:
+                cols.append(jnp.zeros((n, (H - len(kind)) * fhp),
+                                      jnp.float32))
+                kind += [NONE] * (H - len(kind))
+            slope += [0.0] * rest
+            els.append(jnp.zeros((n, rest), jnp.float32))
+            ers.append(jnp.zeros((plan.n_rows, rest), jnp.float32))
+            xs.append(jnp.concatenate(cols, axis=1))
+            heads.append(HeadValues(
+                np.asarray(kind, np.int32), np.asarray(slope, np.float32),
+                jnp.concatenate(els, axis=1),
+                to_plan_order(jnp.concatenate(ers, axis=1), plan.inv_perm)))
+            cuts.append(cut)
+        return xs, heads, cuts
+
+    @staticmethod
+    def _head_cut(h0: int, a: Attention, H: int, fhp: int):
+        """The cut of one attention request's answer: its heads, unpadded,
+        ``[n_rows, heads, f_head]``."""
+        return lambda out: out.reshape(out.shape[0], H, fhp)[
+            :, h0:h0 + a.heads, :a.f_head]
 
     # ------------------------------------------------------------ autotuning
     def _effective_launch(self, plans: List[PartitionPlan]
@@ -900,6 +1062,9 @@ class GraphServeEngine:
             routed_hbm=self.backend_dispatches["hbm"],
             hbm_gather_passes=self.hbm_gather_passes,
             routed_blocked=self.backend_dispatches["blocked"],
+            # graph attention: dispatches with per-call values, their heads
+            attn_dispatches=self.attn_dispatches,
+            attn_heads=self.attn_heads,
             # block bucketing waste: padded/live == 1.0 means no dead steps
             live_blocks=self.live_blocks,
             padded_blocks=self.padded_blocks,

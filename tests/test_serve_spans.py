@@ -23,6 +23,9 @@ from conftest import make_powerlaw_csr
 HOLD_MS = 50.0   # long enough that the worker always holds a lone request
 ALL_SPANS = (spans.SCHED_HOLD, spans.DISPATCH, *spans.DISPATCH_PHASES,
              spans.PLAN_BUILD)
+# a dispatch of plain requests only has every phase but the attention scores
+PLAIN_PHASES = tuple(p for p in spans.DISPATCH_PHASES if p != spans.SCORES)
+PLAIN_SPANS = tuple(s for s in ALL_SPANS if s != spans.SCORES)
 COUNTERS = ["dispatch_prepare_s", "dispatch_merge_s", "dispatch_upload_s",
             "dispatch_launch_s", "dispatch_wait_s", "dispatch_answer_s",
             "sched_queue_wait_s"]
@@ -38,18 +41,17 @@ def _graph_and_x(seed=0, n=300, f=16):
     return g, x
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    """Host-plane events of one register + one ``submit().result()``, each
-    as ``(line index, name, start_ns, end_ns)``."""
+def _trace(trace_dir, submit):
+    """Host-plane events of one register, then ``submit(engine, g, x)``
+    under ``jax.profiler.trace``, each as ``(line index, name, start_ns,
+    end_ns)``."""
     from jax.profiler import ProfileData
-    trace_dir = str(tmp_path_factory.mktemp("trace"))
     engine = _engine()
     g, x = _graph_and_x()
     try:
         with jax.profiler.trace(trace_dir):
             engine.register_graph("g", g)
-            engine.submit("g", x).result()
+            submit(engine, g, x)
             # the future resolves inside gcn.dispatch: let the flush
             # thread close its spans before the trace stops
             engine.close()
@@ -64,18 +66,57 @@ def traced(tmp_path_factory):
             if ev.name in ALL_SPANS]
 
 
-@pytest.mark.parametrize("name", ALL_SPANS)
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One ``submit().result()`` of a plain request."""
+    return _trace(str(tmp_path_factory.mktemp("trace")),
+                  lambda engine, g, x: engine.submit("g", x).result())
+
+
+@pytest.fixture(scope="module")
+def traced_attention(tmp_path_factory):
+    """A plain and an attention request in one flush: one dispatch through
+    every phase, ``scores`` included."""
+    def submit(engine, g, x):
+        scores = np.ones((g.n_rows, 1), np.float32)
+        plain = engine.submit("g", x)
+        engine.submit("g", x[:, None, :],
+                      attention=(scores, scores)).result()
+        plain.result()
+    return _trace(str(tmp_path_factory.mktemp("trace")), submit)
+
+
+def _assert_phases_nest(events, phases_expected):
+    dispatches = [ev for ev in events if ev[1] == spans.DISPATCH]
+    phases = [ev for ev in events if ev[1] in spans.DISPATCH_PHASES]
+    assert len(dispatches) == 1 and len(phases) >= len(phases_expected)
+    line, _, start, end = dispatches[0]
+    for ev in phases:
+        assert ev[0] == line and start <= ev[2] <= ev[3] <= end, ev
+
+
+@pytest.mark.parametrize("name", PLAIN_SPANS)
 def test_span_is_on_the_host_plane(traced, name):
     assert any(ev[1] == name for ev in traced), name
 
 
+def test_plain_dispatch_has_no_scores_span(traced):
+    assert not any(ev[1] == spans.SCORES for ev in traced)
+
+
+@pytest.mark.parametrize("name", ALL_SPANS)
+def test_span_of_an_attention_dispatch_is_on_the_host_plane(
+        traced_attention, name):
+    assert any(ev[1] == name for ev in traced_attention), name
+
+
 def test_dispatch_phases_nest_inside_the_dispatch_span(traced):
-    dispatches = [ev for ev in traced if ev[1] == spans.DISPATCH]
-    phases = [ev for ev in traced if ev[1] in spans.DISPATCH_PHASES]
-    assert len(dispatches) == 1 and len(phases) >= len(spans.DISPATCH_PHASES)
-    line, _, start, end = dispatches[0]
-    for ev in phases:
-        assert ev[0] == line and start <= ev[2] <= ev[3] <= end, ev
+    _assert_phases_nest(traced, PLAIN_PHASES)
+
+
+def test_attention_dispatch_phases_nest_inside_the_dispatch_span(
+        traced_attention):
+    _assert_phases_nest(traced_attention, spans.DISPATCH_PHASES)
 
 
 def test_phase_counters_rise_and_split_total_serve_s():
