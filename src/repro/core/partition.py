@@ -52,6 +52,7 @@ __all__ = [
     "block_level_partition",
     "warp_level_partition",
     "pack_slabs",
+    "slab_layout_ok",
     "balance_stats",
     "metadata_bytes",
 ]
@@ -406,6 +407,33 @@ def pack_slabs(
                        bp.n_rows).astype(np.int32, copy=False)
     return {"colidx": colidx, "values": values, "rowloc": rowloc,
             "out_row": out_row, "R": R, "C": C}
+
+
+def slab_layout_ok(out_row, n_rows: int) -> bool:
+    """Whether ``out_row`` [B, R] has the layout :func:`pack_slabs` gives and
+    the kernels' epilogue (``spmm_accel.scatter_block_rows``) folds: each
+    block's live local rows are a prefix at consecutive output rows, every
+    other slot holds the sentinel ``n_rows``, and a row held by more than
+    one slot (a split row) is local row 0 of blocks of one row each.
+    O(B x R) on the host."""
+    out_row = np.asarray(out_row)
+    if out_row.ndim != 2:
+        return False
+    if not out_row.size:
+        return True
+    if out_row.min() < 0 or out_row.max() > n_rows:
+        return False
+    live = out_row != n_rows
+    n_b = live.sum(axis=1)
+    r = np.arange(out_row.shape[1])
+    if not np.array_equal(live, r < n_b[:, None]):
+        return False
+    if not np.all(~live | (out_row == out_row[:, :1] + r)):
+        return False
+    slots = np.bincount(out_row[live], minlength=n_rows + 1)
+    if (slots[out_row[:, 1:][live[:, 1:]]] > 1).any():
+        return False
+    return bool((n_b[live[:, 0] & (slots[out_row[:, 0]] > 1)] == 1).all())
 
 
 # ---------------------------------------------------------------------------

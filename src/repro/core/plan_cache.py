@@ -38,6 +38,7 @@ from .partition import (
     block_level_partition,
     get_partition_patterns,
     pack_slabs,
+    slab_layout_ok,
 )
 from .spans import PLAN_BUILD, span
 
@@ -198,7 +199,10 @@ class PlanCache:
     between processes serving the same graphs); a later miss reloads the
     spilled plan instead of re-running the partition pipeline. ``spills`` /
     ``disk_hits`` counters track both sides; a disk reload still counts as
-    a ``miss`` but not as a ``build``.
+    a ``miss`` but not as a ``build``. A spill whose slabs lack the layout
+    the kernels fold (``partition.slab_layout_ok``), such as one written by
+    an older plan repair, is deleted and rebuilt, counted in
+    ``spill_rejects``.
     """
 
     def __init__(self, capacity: int = 32, save_dir: Optional[str] = None):
@@ -226,6 +230,7 @@ class PlanCache:
         self.build_s = 0.0      # seconds in build_fn (the gcn.plan.build
         #                         span), disk reloads excluded
         self.spills = 0
+        self.spill_rejects = 0      # spills reloaded with a foreign layout
         self.disk_hits = 0
         self.publishes = 0
         self.retired_versions = 0   # old versions parked behind live pins
@@ -472,7 +477,8 @@ class PlanCache:
 
     def _load_from_disk(self, key: Tuple[str, PartitionConfig]
                         ) -> Optional[PartitionPlan]:
-        """Reload a spilled plan; None when absent/unreadable (then rebuild)."""
+        """Reload a spilled plan; None when absent/unreadable or when its
+        slab layout is not the one the kernels fold (then rebuild)."""
         if self.save_dir is None:
             return None
         path = self._spill_path(key)
@@ -481,6 +487,11 @@ class PlanCache:
         _, cfg = key
         try:
             with np.load(path) as z:
+                if not slab_layout_ok(z["slab_out_row"], int(z["n_rows"])):
+                    with self._lock:
+                        self.spill_rejects += 1
+                    os.unlink(path)  # a fresh spill may take its name
+                    return None
                 slabs = {
                     "colidx": jnp.asarray(z["slab_colidx"]),
                     "values": jnp.asarray(z["slab_values"]),
@@ -540,6 +551,7 @@ class PlanCache:
                 "evictions": self.evictions,
                 "spills": self.spills,
                 "disk_hits": self.disk_hits,
+                "spill_rejects": self.spill_rejects,
                 "publishes": self.publishes,
                 "pins": sum(self._pins.values()),
                 "retired_versions": self.retired_versions,

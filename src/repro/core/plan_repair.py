@@ -8,29 +8,30 @@ degree-5 row dirties the entire degree-5 class (often a third of the graph),
 so class-granular repair falls back to a full rebuild for even 0.1% deltas.
 
 :func:`repair_plan` instead repairs at **stable output positions**. Every
-kernel backend scatters block outputs with ``segment_sum`` over the plan's
-``out_row`` slab, so neither block ORDER nor the monotonicity of output
-positions matters to the SpMM result — only that each row's non-zeros land
-in some block whose ``out_row`` names that row's position. That licenses:
+kernel's epilogue (``spmm_accel.scatter_block_rows``) folds block outputs
+through the plan's ``out_row`` slab in any block order, as long as each
+block covers a run of consecutive positions (a split row: one position per
+block), and blocks of several rows cover disjoint runs. That licenses:
 
 1. keep the old permutation verbatim: every row keeps its output position,
    ``inv_perm`` is reused by reference (touched rows' positions become
    output *slots*, not sort ranks);
-2. MASK instead of rewrite: rewriting every touched row's slots to the
-   drop sentinel ``n`` (an O(B x R) vectorized lookup over ``out_row``
-   alone) deletes the row's old edges from the SpMM output without
-   touching ``colidx``/``values``/``rowloc`` — untouched rows sharing the
-   same blocks keep their lanes, so there is no re-emission amplification,
-   and the lookup works unchanged on already-repaired plans (chained
-   repairs need no extra bookkeeping);
-3. re-emit ONLY the touched rows (not their blocks' cohabitants): build a
-   degree-sorted sub-CSR of just those rows, run
-   ``block_level_partition`` + ``pack_slabs`` over it with ``block_rows``
-   clamped to the old plan's R (so the slabs stay rectangular with
-   matching sentinels), then remap the sub plan's local ``out_row``
-   indices to the rows' stable global positions;
+2. MASK instead of rewrite: rewriting each block's slots from its first
+   touched row on to the drop sentinel ``n`` (an O(B x R) vectorized lookup
+   over ``out_row`` alone) deletes those rows from the SpMM output without
+   touching ``colidx``/``values``/``rowloc``; the rows before the first
+   touched one stay, still a prefix at consecutive positions, and the
+   lookup works unchanged on already-repaired plans (chained repairs need
+   no extra bookkeeping);
+3. re-emit the dirty range: every masked row and every touched row, as a
+   sub-CSR in position order, through ``block_level_partition`` +
+   ``pack_slabs`` with ``block_rows`` clamped to the old plan's R (so the
+   slabs stay rectangular with matching sentinels); each fresh block then
+   covers consecutive positions, and its local ``out_row`` indices map to
+   them. A touched row drags the rest of its block along (at most R - 1
+   rows), which keeps the layout whole;
 4. splice = append: the big [B, C] slabs are concatenated on device (the
-   old blocks survive byte-for-byte, dead lanes silenced purely through
+   old blocks survive byte-for-byte, masked rows silenced purely through
    the patched host-side ``out_row``), the re-emitted blocks ride behind.
 
 The repaired plan is **SpMM-output-identical** to a fresh
@@ -249,12 +250,6 @@ def repair_plan(plan: PartitionPlan, g_old: CSRGraph, g_new: CSRGraph,
                            reason="empty delta",
                            reused_blocks=plan.num_blocks)
 
-    if len(touched) > churn_threshold * max(n, 1):
-        return _rebuild(
-            plan, g_new,
-            f"churn {len(touched)}/{n} rows > threshold {churn_threshold}",
-            graph_hash=graph_hash)
-
     bp = plan.partition
     pats = bp.patterns
     R_old = int(plan.slabs["R"])
@@ -263,27 +258,47 @@ def repair_plan(plan: PartitionPlan, g_old: CSRGraph, g_new: CSRGraph,
     # row -> stable output position (kept verbatim; see module docstring)
     inv_old = np.asarray(plan.inv_perm, dtype=np.int64)
 
-    # MASK the touched rows out of every block they occupy: a lane whose
-    # out_row slot is the drop sentinel contributes nothing to segment_sum,
-    # so pointing a row's slots at ``n`` deletes its old edges from the
-    # output without touching colidx/values/rowloc. Untouched rows of the
-    # same block keep their slots — no re-emission amplification.
+    # MASK each block from its first touched row on: a lane whose out_row
+    # slot is the drop sentinel contributes nothing, so pointing those
+    # slots at ``n`` deletes their rows from the output without touching
+    # colidx/values/rowloc, and the rows before stay a prefix.
     old_out_row = np.asarray(plan.slabs["out_row"])
     touched_pos = np.zeros(n + 1, dtype=bool)  # slot n = drop sentinel
     touched_pos[inv_old[touched]] = True
-    dead = touched_pos[old_out_row]
-    patched_out = np.where(dead, np.int32(n), old_out_row).astype(
+    cut = np.logical_or.accumulate(touched_pos[old_out_row], axis=1)
+    patched_out = np.where(cut, np.int32(n), old_out_row).astype(
         np.int32, copy=False)
-    masked_blocks = int(dead.any(axis=1).sum())
+    masked_blocks = int(cut[:, -1].sum()) if cut.size else 0
 
-    # re-emit ONLY the touched rows (empty rows emit nothing), appended as
-    # fresh blocks from a degree-sorted sub-CSR
-    sub_rows = touched[deg_new[touched] > 0]
-    sub_rows = sub_rows[np.lexsort((sub_rows, deg_new[sub_rows]))]
+    # re-emit the dirty range: every masked row and every touched row, in
+    # position order, so each fresh block covers consecutive
+    # positions (the slab layout ``spmm_accel.scatter_block_rows`` relies
+    # on); a zero-degree row between runs of positions keeps any block from
+    # spanning a gap, and empty rows emit nothing. The sub-CSR is not
+    # degree-sorted: block_level_partition packs each run of equal degree,
+    # so a row whose degree changed only breaks its run into more blocks.
+    emit = touched_pos.copy()
+    emit[old_out_row[cut]] = True
+    positions = np.flatnonzero(emit[:n])
+    if len(positions) > churn_threshold * max(n, 1):
+        return _rebuild(
+            plan, g_new,
+            f"churn {len(positions)}/{n} rows to re-emit > threshold "
+            f"{churn_threshold}", graph_hash=graph_hash)
+    slot = np.arange(len(positions)) + np.concatenate(
+        ([0], np.cumsum(np.diff(positions) > 1)))
+    n_sub = int(slot[-1]) + 1
+    pos_map = np.full(n_sub + 1, n, dtype=np.int32)  # sub sentinel -> n
+    pos_map[slot] = positions
+    row_at = np.empty(n, dtype=np.int64)
+    row_at[inv_old] = np.arange(n)
+    sub_rows = row_at[positions]
     degs = deg_new[sub_rows]
     total = int(degs.sum())
-    sub_rowptr = np.zeros(len(sub_rows) + 1, dtype=np.int64)
-    np.cumsum(degs, out=sub_rowptr[1:])
+    sub_deg = np.zeros(n_sub, dtype=np.int64)
+    sub_deg[slot] = degs
+    sub_rowptr = np.zeros(n_sub + 1, dtype=np.int64)
+    np.cumsum(sub_deg, out=sub_rowptr[1:])
     gather = _concat_ranges(g_new.rowptr[sub_rows], degs, total)
     # columns stay GLOBAL: SpMM's dense operand is the full feature matrix,
     # so sub-slabs index it directly — no column remap on splice
@@ -306,23 +321,15 @@ def repair_plan(plan: PartitionPlan, g_old: CSRGraph, g_new: CSRGraph,
             graph_hash=graph_hash)
 
     # remap the sub plan's local row indices to stable global positions
-    pos_map = inv_old[sub_rows].astype(np.int32)
-    n_sub = len(sub_rows)
-    if n_sub:
-        sub_out_row = np.where(
-            sub_slabs["out_row"] == n_sub, np.int32(n),
-            pos_map[np.minimum(sub_slabs["out_row"], n_sub - 1)]
-        ).astype(np.int32)
-    else:
-        sub_out_row = sub_slabs["out_row"]  # (0, R) — nothing to remap
+    sub_out_row = pos_map[sub_slabs["out_row"]]
     sub_meta = sub_bp.meta.copy()
     if len(sub_meta):
         sub_meta[:, 1] = -1  # sub-CSR nnz offsets are meaningless globally
         sub_meta[:, 2] = pos_map[sub_meta[:, 2]]
 
-    # splice: every old block survives verbatim (dead lanes masked via
-    # patched_out), re-emitted blocks appended — block order is irrelevant,
-    # every kernel scatters through out_row. The big [B, C] slabs are
+    # splice: every old block survives verbatim (dirty rows masked via
+    # patched_out), re-emitted blocks appended — block order is
+    # irrelevant to the kernels' epilogue. The big [B, C] slabs are
     # concatenated ON DEVICE; only out_row ([B, R], an order of magnitude
     # smaller) ever visits the host, for the mask.
     C = int(plan.slabs["C"])
@@ -359,9 +366,10 @@ def repair_plan(plan: PartitionPlan, g_old: CSRGraph, g_new: CSRGraph,
     )
     return PlanVersion(
         plan=new_plan, version=new_plan.version, repaired=True,
-        reason=f"masked {len(touched)} row(s) across {masked_blocks} "
-               f"block(s), re-emitted {rebuilt} block(s)",
-        dirty_rows=len(touched), reused_blocks=reused,
+        reason=f"masked {masked_blocks} block(s) from their first of "
+               f"{len(touched)} touched row(s), re-emitted {len(positions)} "
+               f"row(s) as {rebuilt} block(s)",
+        dirty_rows=len(positions), reused_blocks=reused,
         rebuilt_blocks=rebuilt)
 
 

@@ -447,7 +447,8 @@ class FleetPlanCache:
         per = [s.stats() for s in self.shards]
         agg: Dict[str, float] = {}
         for k in ("size", "lookups", "hits", "misses", "builds", "build_s",
-                  "evictions", "spills", "disk_hits", "device_bytes",
+                  "evictions", "spills", "disk_hits", "spill_rejects",
+                  "device_bytes",
                   "publishes", "pins", "retired_versions",
                   "retired_reclaimed", "retired_live"):
             agg[k] = sum(p[k] for p in per)

@@ -19,7 +19,7 @@ so the suite exercises real multi-device semantics without hardware):
   spreads the heavy dense-row blocks and the light multi-row blocks evenly
   — AWB-GCN's workload rebalancing across processing elements, applied at
   device granularity. X is replicated (all-gathered once), each device
-  scatters its block subset into a full-height partial result, and a
+  folds its block subset into a full-height partial result, and a
   ``psum`` over the mesh adds the per-device row slabs back together
   (split rows — degree > C, continued across blocks that may now live on
   different devices — are exactly why the combine is an add).
@@ -215,7 +215,7 @@ def spmm_block_sharded(slabs: Dict, x: jax.Array, n_rows: int, mesh: Mesh,
                        ) -> Tuple[jax.Array, np.ndarray]:
     """A' @ X with the plan's blocks round-robin across ``mesh`` devices.
 
-    X is replicated across the mesh; each device scatters its block subset
+    X is replicated across the mesh; each device folds its block subset
     into a full ``[n_rows, F]`` partial and a ``psum`` adds the per-device
     row slabs back together. Returns ``(out, live_counts)`` — the per-device
     REAL block counts, the balance evidence the fleet stats export.

@@ -12,9 +12,11 @@ TPU mapping of the paper's design (the TPU pattern mode of
 * the intra-block segment reduction (the paper's shared-memory
   ``atomicAdd_block``) becomes a weighted one-hot MXU matmul
   ``[R, C] @ [C, F_tile]`` entirely in VMEM — no atomics exist or are needed;
-* cross-block accumulation for split rows (degree > C) is a segment-sum
-  epilogue over the packed block outputs (TPU grids are sequential, so a
-  revisit-accumulate output alias is also legal; see ops.py notes).
+* the packed ``[B, R, F_pad]`` block outputs fold into ``[n_rows, F]`` in
+  one shared epilogue, :func:`scatter_block_rows`, which reads each kept
+  slab row once: every answer row by one gather from a block that covers
+  it, then a scatter-add of each block's local row 0 that adds the other
+  partials of a row split over blocks (degree > C).
 
 Slab metadata enters each grid step as ``[1, 1, C]`` blocks of the
 ``[B, 1, C]`` view of the ``[B, C]`` slabs (a ``(1, C)`` block of a
@@ -85,34 +87,52 @@ from .router import (
 
 
 DEFAULT_F_TILE = 128  # lane width — the "combined warp" quantum on TPU
-SCATTER_CHUNK = 64    # most blocks folded by one scatter-add of the epilogue
 
 
 def scatter_block_rows(out_slabs: jax.Array, out_row: jax.Array,
                        n_rows: int, n_features: int) -> jax.Array:
-    """Shared scatter epilogue of every slab kernel: packed [B, R, F_pad]
-    block rows -> global [n_rows, n_features]. Non-split blocks write
-    disjoint rows; split-row blocks accumulate; slot n_rows is the padding
-    sentinel and is dropped (sequential-grid revisit accumulation is the
-    real-TPU alternative).
+    """Shared epilogue of every slab kernel: packed [B, R, F_pad] block
+    rows -> global [n_rows, n_features], reading each slab row it keeps
+    once (n_rows + B rows, where a scatter-add of every slot reads B * R).
 
-    Blocks fold in order, ``chunk`` at a time (the largest power of two up
-    to ``SCATTER_CHUNK`` that divides B), by a loop of small scatter-adds:
-    one scatter over all B*R rows takes the TPU compiler tens of seconds at
-    full-graph sizes, the loop under one.
+    It relies on the layout ``partition.pack_slabs`` gives and the batched
+    merge and plan repair keep, in any block order:
+
+    * block b's live local rows are a prefix ``r < n_b``, with
+      ``out_row[b, r] = out_row[b, 0] + r``; the rest hold the drop
+      sentinel ``n_rows``;
+    * blocks of more than one row cover disjoint row ranges;
+    * a row split over several blocks (degree > C) is local row 0 of each.
+
+    Every row is one gather from a block that covers it: each block's index
+    is written at its first row and filled forward (a running maximum over
+    the rows), and so is its end. A split row gathers its first block's
+    partial, and its later blocks add theirs by a scatter of local row 0,
+    B rows, in block order as a scatter-add of every slot would. Rows that
+    no block covers (no non-zeros) come out zero.
     """
     B, R, F_pad = out_slabs.shape
-    acc = jnp.zeros((n_rows + 1, F_pad), out_slabs.dtype)
-    if B:
-        chunk = min(B & -B, SCATTER_CHUNK)
-        rows = out_slabs.reshape(B // chunk, chunk * R, F_pad)
-        seg = out_row.reshape(B // chunk, chunk * R)
-        # the first chunk seeds the carry, so under shard_map it carries
-        # the same device-varying type as every later step
-        acc = jax.lax.fori_loop(
-            1, B // chunk, lambda i, a: a.at[seg[i]].add(rows[i]),
-            acc.at[seg[0]].add(rows[0]))
-    return acc[:n_rows, :n_features]
+    if not B:
+        return jnp.zeros((n_rows, n_features), out_slabs.dtype)
+    first = out_row[:, 0]
+    n_b = jnp.sum(out_row != n_rows, axis=1, dtype=jnp.int32)
+    blk = jnp.arange(B, dtype=jnp.int32)
+    owner = jnp.full((n_rows + 1,), B, jnp.int32).at[first].min(blk)
+    end = jnp.zeros((n_rows + 1,), jnp.int32).at[first].max(first + n_b)
+    pos = jnp.arange(n_rows, dtype=jnp.int32)
+    start = jax.lax.cummax(jnp.where(owner[:n_rows] < B, pos, -1))
+    # ranges are disjoint, so the running maximum of the ends is the end of
+    # the block that starts last at or before each row
+    body = pos < jax.lax.cummax(end[:n_rows])
+    src = jnp.where(body, owner[jnp.maximum(start, 0)] * R + pos - start, 0)
+    # whole padded rows: the TPU lowers a gather of fewer lanes than the
+    # row holds (F = 40 of 128) to a loop of one-row copies
+    rows = out_slabs.reshape(B * R, F_pad).at[src].get(
+        mode="promise_in_bounds")
+    out = jnp.where(body[:, None], rows, 0.0)
+    rest = jnp.where(owner[first] == blk, n_rows, first)  # n_rows: dropped
+    out = out.at[rest].add(out_slabs[:, 0], mode="drop")
+    return out[:, :n_features]
 
 
 def slab_meta_specs(C: int, index_map, values_map=None, heads: int = 1):

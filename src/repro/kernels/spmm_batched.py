@@ -10,8 +10,8 @@ already iterates block-by-block. So a batch of graphs fuses by construction:
    remapped to the single batch-wide sentinel ``N_out``), then concatenate
    along the block axis;
 3. route the merged ``[B_total, C]`` slabs + row-concatenated features to a
-   single-graph kernel — ONE compilation, one dispatch, one scatter
-   epilogue. The concatenated feature matrix is where a batch of
+   single-graph kernel — ONE compilation, one dispatch, one epilogue.
+   The concatenated feature matrix is where a batch of
    individually-fine graphs silently overflows the resident kernel's VMEM
    budget (N_pad multiplies by batch size!), so ``backend="auto"`` asks
    ``router.route_spmm`` to pick resident / windowed / HBM-gather from the
@@ -19,9 +19,12 @@ already iterates block-by-block. So a batch of graphs fuses by construction:
    ``VmemBudgetError`` instead of silently compiling an oversized tile;
 4. slice each graph's rows back out of the batched output.
 
-Padding slab slots carry value 0 and padding block rows scatter to the
+Padding slab slots carry value 0 and padding block rows map to the
 sentinel row, so fused outputs are bit-identical in structure to per-graph
-runs (fp32 reduction order within a block is unchanged).
+runs (fp32 reduction order within a block is unchanged). The merge keeps
+the slab layout the kernels' epilogue relies on
+(``spmm_accel.scatter_block_rows``): each graph's blocks cover consecutive
+output rows, shifted by one offset, and padding is all sentinel.
 
 ``pad_blocks_to`` rounds the merged block count up to a bucket so repeated
 batches with different graph mixes reuse one compiled kernel.

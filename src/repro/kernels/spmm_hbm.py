@@ -53,6 +53,12 @@ slots carry value 0 with an in-bounds index, and fully-padded bucket blocks
 (all values zero) skip their DMA loop entirely and write a zero output
 block — so block-count bucketing costs bandwidth only for live blocks.
 
+The kernel writes ``[B, R, F_pad]`` f32, and the shared epilogue
+``spmm_accel.scatter_block_rows`` folds it into ``[n_rows, F]`` in the same
+jitted module: one gather of every answer row, then a scatter-add of each
+block's local row 0 for rows split over blocks; n_rows + B slab rows read,
+where a scatter-add of every slot would read B * R.
+
 Validated in interpret mode against the same oracle as the resident-X
 kernel, and compiled by Mosaic on a TPU (``tests/test_tpu_compile.py``).
 """

@@ -211,6 +211,9 @@ class GraphServeEngine:
         # row-gather passes each block of an hbm dispatch makes (F_pad / W,
         # router.hbm_gather_width), summed over hbm dispatches
         self.hbm_gather_passes = 0
+        # slab rows the kernels' epilogue reads (scatter_block_rows): the
+        # merged n_out + B of each dispatch
+        self.epilogue_rows = 0
         self.last_decision: Optional[RoutingDecision] = None
         # mutation-path counters (versioned plan lifecycle)
         self.mutations_applied = 0   # mutate() requests resolved
@@ -719,6 +722,8 @@ class GraphServeEngine:
                 self.hbm_gather_passes += (decision.f_pad
                                            // decision.gather_width)
             self.last_decision = decision
+            self.epilogue_rows += (sum(p.n_rows for p in plans)
+                                   + (pad_to or b_total))
             self.live_blocks += b_total
             self.padded_blocks += pad_to if pad_to else b_total
             if heads is not None:
@@ -1061,6 +1066,7 @@ class GraphServeEngine:
             routed_windowed=self.backend_dispatches["windowed"],
             routed_hbm=self.backend_dispatches["hbm"],
             hbm_gather_passes=self.hbm_gather_passes,
+            epilogue_rows=self.epilogue_rows,
             routed_blocked=self.backend_dispatches["blocked"],
             # graph attention: dispatches with per-call values, their heads
             attn_dispatches=self.attn_dispatches,

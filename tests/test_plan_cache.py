@@ -233,6 +233,81 @@ def test_corrupt_spill_falls_back_to_rebuild(tmp_path):
     assert cache.builds == 3
 
 
+def _scattered_repair(plan):
+    """``plan`` as an older plan repair left it: two rows of one degree,
+    each off local row 0 of a block of several rows, masked in place (holes)
+    and re-emitted together in one appended block at positions that are not
+    consecutive. The segment sum still answers right; the slab layout the
+    kernels fold does not hold."""
+    import dataclasses
+    import jax.numpy as jnp
+    out_row = np.asarray(plan.slabs["out_row"]).copy()
+    colidx, values, rowloc = (np.asarray(plan.slabs[k]) for k in
+                              ("colidx", "values", "rowloc"))
+    n, R, C = plan.n_rows, int(plan.slabs["R"]), int(plan.slabs["C"])
+    deg = plan.partition.meta[:, 0]
+    many = (out_row[:, 2] != n) & ~plan.partition.is_split
+    d = int(np.bincount(deg[many]).argmax())
+    (b1, b2) = np.flatnonzero(many & (deg == d))[[0, -1]]
+    new = [np.zeros(C, np.int32), np.zeros(C, np.float32),
+           np.full(C, R - 1, np.int32), np.full(R, n, np.int32)]
+    for j, b in enumerate((b1, b2)):
+        lane = rowloc[b] == 1
+        new[0][j * d:(j + 1) * d] = colidx[b][lane]
+        new[1][j * d:(j + 1) * d] = values[b][lane]
+        new[2][j * d:(j + 1) * d] = j
+        new[3][j] = out_row[b, 1]
+        out_row[b, 1] = n
+    slabs = dict(plan.slabs)
+    for k, a, extra in zip(("colidx", "values", "rowloc", "out_row"),
+                           (colidx, values, rowloc, out_row), new):
+        slabs[k] = jnp.asarray(np.concatenate([a, extra[None]]))
+    return dataclasses.replace(plan, slabs=slabs)
+
+
+def test_spill_of_an_older_repair_layout_is_rebuilt(tmp_path):
+    """A spilled plan whose slabs lack the layout the kernels fold (holes in
+    a block, a block at scattered rows, as an older plan repair left them)
+    is not loaded: it is deleted and rebuilt, and the rebuilt plan answers
+    as the graph does and spills again under the same name."""
+    import jax.numpy as jnp
+    from repro.core.partition import slab_layout_ok
+    from repro.kernels.ops import spmm_batched
+    from repro.kernels.ref import csr_spmm_ref, slab_spmm_ref
+    cfg = PartitionConfig(max_block_warps=4, max_warp_nzs=2)
+    g = _g(7)
+    old = _scattered_repair(build_partition_plan(g, cfg))
+    sl = [old.slabs[k] for k in ("colidx", "values", "rowloc", "out_row")]
+    assert not slab_layout_ok(old.slabs["out_row"], old.n_rows)
+    X = jnp.asarray(np.random.default_rng(0).normal(size=(g.n_rows, 8)),
+                    dtype=jnp.float32)
+    ref = np.asarray(csr_spmm_ref(g.rowptr, g.colidx, g.values, X))
+    perm = np.asarray(old.inv_perm)
+    np.testing.assert_allclose(
+        np.asarray(slab_spmm_ref(*sl, X, old.n_rows))[perm], ref,
+        atol=1e-4, rtol=1e-4)
+
+    cache = PlanCache(capacity=1, save_dir=str(tmp_path))
+    cache.put(old)
+    cache.get_or_build(_g(8), cfg)       # evicts + spills the old layout
+    assert cache.stats()["spills"] == 1
+    plan = cache.get_or_build(g, cfg)    # refused on load: rebuilt
+    st = cache.stats()
+    assert (st["spill_rejects"], st["disk_hits"], st["builds"]) == (1, 0, 2)
+    assert not list(tmp_path.glob(f"{plan.key[0]}-*.npz"))
+    assert slab_layout_ok(plan.slabs["out_row"], plan.n_rows)
+    got = spmm_batched([plan.slabs], [X], [plan.n_rows], backend="pallas")[0]
+    np.testing.assert_allclose(np.asarray(got)[np.asarray(plan.inv_perm)],
+                               ref, atol=1e-4, rtol=1e-4)
+
+    cache.get_or_build(_g(8), cfg)       # the rebuilt plan spills ...
+    again = cache.get_or_build(g, cfg)   # ... and reloads from disk
+    st = cache.stats()                   # (so do both of _g(8)'s reloads)
+    assert (st["spill_rejects"], st["disk_hits"], st["builds"]) == (1, 2, 2)
+    np.testing.assert_array_equal(np.asarray(again.slabs["out_row"]),
+                                  np.asarray(plan.slabs["out_row"]))
+
+
 def test_config_tag_distinguishes_spills(tmp_path):
     cache = PlanCache(capacity=1, save_dir=str(tmp_path))
     g = _g(3)

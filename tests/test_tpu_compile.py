@@ -97,8 +97,12 @@ def _compile(fn, B, N, F, sharding, H=1):
 def test_kernel_compiles_for_v5e(kernel, one_chip, no_compile_cache,
                                  compiled_kernels):
     fn, B, N, F, *H = CASES[kernel]
-    compiled = _compile(fn, B, N, F, one_chip, *H)
-    assert "tpu_custom_call" in compiled.as_text()
+    text = _compile(fn, B, N, F, one_chip, *H).as_text()
+    assert "tpu_custom_call" in text
+    # the epilogue (spmm_accel.scatter_block_rows) is whole-array work: a
+    # gather of fewer lanes than a row holds lowers to a loop of one-row
+    # copies, 169,343 steps a call at F=40
+    assert " while(" not in text
 
 
 def test_edge_softmax_compiles_for_v5e(one_chip, no_compile_cache):
