@@ -14,8 +14,7 @@ Emits ``name,us_per_call,derived`` CSV. Sections:
             traffic: continuous-batching scheduler vs per-call dispatch;
             stats also land in benchmarks/results/serve_stats.json)
   routing   resident vs windowed vs HBM-gather vs auto at the VMEM
-            boundaries (mixes that straddle the routing thresholds), and
-            the resident kernel's block_major vs ft_major grid orders
+            boundaries (mixes that straddle the routing thresholds)
   fleet     multi-device serving: FleetGraphEngine vs the single-device
             scheduler on the concurrent mix, plus the block-sharded giant
             graph with per-device balance (merges a "fleet" key into
@@ -25,10 +24,6 @@ Emits ``name,us_per_call,derived`` CSV. Sections:
             multi-process jax) routed by the placement directory —
             forwarded traffic + the collective global-mesh giant (merges
             a "multihost" key into benchmarks/results/serve_stats.json)
-  tune      online partition autotuner: offline candidate ranking, the
-            live shadow-measured promotion loop (steady-state tuned vs
-            default dispatch), and the shadow p99-overhead check (merges
-            a "tuning" key into benchmarks/results/serve_stats.json)
   sample    neighbor-sampling service: zipf seed-stream frontier hit rate,
             sampled-path throughput, full-fanout exactness vs the full
             graph on both backends, and the two-subprocess partitioned
@@ -78,7 +73,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list: fig5,fig6,table2,preproc,repair,"
-                         "serve,routing,fleet,multihost,tune,sample,moe,"
+                         "serve,routing,fleet,multihost,sample,moe,"
                          "roofline")
     ap.add_argument("--budget-edges", type=int, default=200_000)
     args = ap.parse_args()
@@ -89,7 +84,7 @@ def main() -> None:
     # explicitly)
     want = set(args.only.split(",")) if args.only else \
         {"fig5", "fig6", "table2", "preproc", "repair", "serve", "routing",
-         "fleet", "tune", "moe", "roofline"}
+         "fleet", "moe", "roofline"}
 
     print("name,us_per_call,derived")
     if "fig5" in want:
@@ -127,10 +122,6 @@ def main() -> None:
     if "multihost" in want:
         from .multihost_serve import run as multihost
         for r in multihost(budget_edges=args.budget_edges):
-            print(r)
-    if "tune" in want:
-        from .tune_partition import run as tune
-        for r in tune(budget_edges=args.budget_edges):
             print(r)
     if "sample" in want:
         from .sampling_serve import run as sample

@@ -90,23 +90,6 @@ def run(budget_edges: int = 200_000, feat: int = 32) -> List[str]:
                 note = f"exec={backend}"
             rows.append(csv_row(f"routing/{mix_name}_{backend}", us,
                                 f"{note};n_cat={n_cat}"))
-
-        # grid-order experiment (ROADMAP): the resident kernel iterated
-        # (block, feature-tile) vs (feature-tile, block). Outputs are
-        # identical; on hardware ft_major keeps one X tile resident across
-        # the whole block sweep. Interpret-mode timings only rank the
-        # emulation; both orders are recorded for the real-TPU run.
-        for order in ("block_major", "ft_major"):
-            def call_order(order=order):
-                return spmm_batched([p.slabs for p in plans], xs,
-                                    [p.n_rows for p in plans],
-                                    backend="pallas", grid_order=order)
-            try:
-                us = time_call(call_order, warmup=1, iters=3)
-            except VmemBudgetError:
-                break   # mix does not fit the resident kernel at all
-            rows.append(csv_row(f"routing/{mix_name}_grid_{order}", us,
-                                f"exec=resident;n_cat={n_cat}"))
     return rows
 
 

@@ -13,14 +13,8 @@ threads submit single requests (``engine.submit -> Future``) and the
 background scheduler coalesces them into fused cross-caller dispatches —
 the thing the old blocking ``serve()`` fundamentally could not do.
 
-The final section is the online partition autotuner quickstart: pass
-``tuner=PlanTuner(...)`` at engine construction and hot graphs get their
-partition config searched in the background — a fraction of live
-dispatches is duplicated onto candidate plans OFF the critical path
-(reads always answer from the incumbent and never pay for a candidate),
-and a candidate that wins a streak of paired shadow measurements is
-promoted through the plan version chain. ``tune_offline`` is the same
-search as a one-shot CLI (``scripts/tune_partition.py``).
+The final section applies a streaming edge delta (``mutate``) to a live
+graph and checks the repaired plan against a fresh direct operator.
 """
 import argparse
 import threading
@@ -37,7 +31,6 @@ from repro.data.graphs import make_power_law_graph, node_features
 from repro.models.gcn import GraphOp
 from repro.models.layers import dense_init
 from repro.serve.graph_engine import GraphRequest, GraphServeEngine
-from repro.tuning import PlanTuner, tune_offline
 
 
 def main():
@@ -49,12 +42,8 @@ def main():
     ap.add_argument("--rounds", type=int, default=3)
     args = ap.parse_args()
 
-    # tuner quickstart, part 1: attach a PlanTuner and any graph whose
-    # request rate crosses hot_rate gets shadow-tuned in the background
-    tuner = PlanTuner(hot_rate=5.0, shadow_fraction=0.5, win_streak=2,
-                      min_improvement=0.01, max_trials=4)
-    engine = GraphServeEngine(config=PartitionConfig(), tuner=tuner,
-                              backend="blocked", max_graphs_per_batch=4)
+    engine = GraphServeEngine(config=PartitionConfig(), backend="blocked",
+                              max_graphs_per_batch=4)
     graphs = {}
     for i in range(args.graphs):
         gid = f"g{i}"
@@ -166,32 +155,6 @@ def main():
           f"{'repair' if info['repaired'] else 'rebuild'} "
           f"({info['dirty_rows']} dirty rows), post-delta max|err| = "
           f"{merr:.2e}  OK")
-
-    # ---- online partition autotuner quickstart ---------------------------
-    # Part 2: a hot burst on one graph. The tuner duplicates every other
-    # dispatch onto a candidate plan in a background worker (live answers
-    # always come from the incumbent — shadows never touch the read path);
-    # a candidate that wins 2 consecutive paired measurements by >= 1% is
-    # published as the graph's next plan version.
-    x_hot = feats[gid0] @ weights[0]
-    for _ in range(60):
-        engine.serve_one(gid0, x_hot)
-        time.sleep(0.005)       # paced so shadows measure on an idle host
-    ts = engine.stats()
-    tuned = engine.plan_for(gid0).tuned
-    print(f"[serve_gcn] tuner: {ts['shadow_dispatches']:.0f} shadow "
-          f"measurements, {ts['shadow_skipped']:.0f} skipped (worker busy), "
-          f"promotions={ts['tuned_promotions']:.0f}"
-          + (f" -> '{tuned['label']}' now serving" if tuned else
-             " (incumbent still best on this mix)"))
-    # Part 3: the same search as a one-shot offline ranking (what
-    # scripts/tune_partition.py prints for a saved graph)
-    off = tune_offline(graphs[gid0], feat_dim=8, repeats=1)
-    best = off["best"]
-    if best is not None:
-        print(f"[serve_gcn] tune_offline: best candidate "
-              f"'{best['label']}' at {best['speedup_vs_base']:.2f}x vs "
-              f"the default config")
     engine.close()
 
 

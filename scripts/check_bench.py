@@ -8,8 +8,7 @@ and
 * asserts the ABSOLUTE acceptance properties of the serving stack
   (cross-caller coalescing, fleet-vs-single coalescing, block-shard
   balance, zipf hot-plan replication, incremental plan repair >= 3x a
-  full rebuild at 0.1% churn, online partition autotuner promoting a
-  non-default config whose steady state is >= 1.0x the default), and
+  full rebuild at 0.1% churn), and
 * compares throughput rows against a COMMITTED baseline
   (``benchmarks/baselines/serve_stats.baseline.json``), failing on a
   >20% drop so perf regressions surface as red nightlies instead of
@@ -151,35 +150,6 @@ def check_multihost(g: Gate, s: Dict) -> None:
             f"global block shard balanced: {bc}")
 
 
-def check_tuning(g: Gate, s: Dict, *, parallel: bool) -> None:
-    t = s.get("tuning")
-    if t is None:
-        g.check(False, "tuning section present in results "
-                       "(run benchmarks with --only tune)")
-        return
-    on = t["online"]
-    g.check(on["promotions"] >= 1,
-            f"online tuner promoted a config: promotions={on['promotions']}")
-    g.check(not on["tuned_config_default"],
-            f"promoted config is non-default: label={on['tuned_label']}")
-    sp = on["tuned_speedup"]
-    g.check(sp >= 1.0,
-            f"tuned steady-state beats default dispatch: {sp:.2f}x >= 1.0x")
-    off = t["offline"]
-    g.check(off["best_speedup"] >= 1.0,
-            f"offline search found headroom: best={off['best_label']} "
-            f"{off['best_speedup']:.2f}x >= 1.0x")
-    ratio = t["shadow"]["p99_ratio"]
-    if parallel:
-        g.check(ratio <= 1.05,
-                f"shadowing off the critical path: p99 ratio "
-                f"{ratio:.3f} <= 1.05 vs tuner disabled")
-    else:
-        g.info(f"single-core host (cpu_count={os.cpu_count()}): shadow p99 "
-               f"ratio={ratio:.3f} reported only — the shadow worker "
-               f"shares the lone core with live dispatches")
-
-
 def check_sampling(g: Gate, s: Dict) -> None:
     sm = s.get("sampling")
     if sm is None:
@@ -243,9 +213,6 @@ def main(argv=None) -> int:
     ap.add_argument("--require-repair", action="store_true",
                     help="also gate the plan-repair section (produced by "
                          "--only repair; nightly runs it)")
-    ap.add_argument("--require-tuning", action="store_true",
-                    help="also gate the partition-autotuner section "
-                         "(produced by --only tune; nightly runs it)")
     ap.add_argument("--require-sampling", action="store_true",
                     help="also gate the neighbor-sampling section "
                          "(produced by --only sample; nightly runs it)")
@@ -275,11 +242,6 @@ def main(argv=None) -> int:
     else:
         g.info("repair section absent, skipped "
                "(pass --require-repair to make that a failure)")
-    if args.require_tuning or "tuning" in s:
-        check_tuning(g, s, parallel=parallel)
-    else:
-        g.info("tuning section absent, skipped "
-               "(pass --require-tuning to make that a failure)")
     if args.require_sampling or "sampling" in s:
         check_sampling(g, s)
     else:

@@ -9,10 +9,6 @@ appends the probe-extrapolated roofline vector to
 
 ``VARIANTS`` below are named LM flag bundles (attention precision, MoE
 dispatch, sharding levers) — they do NOT cover the graph-serving stack.
-Partition/SpMM tuning moved to its own tools: ``scripts/tune_partition.py``
-for offline one-shot tuning of a saved graph, and
-:class:`repro.tuning.PlanTuner` for the online shadow-measured autotuner
-inside the serving engines (see ``src/repro/tuning/``).
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"

@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import json
 import os
 import threading
 from collections import OrderedDict
@@ -53,24 +52,13 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class PartitionConfig:
-    """Static knobs that change the partition layout (part of the cache key).
-
-    ``warp_nzs_table`` is the tuner's per-degree warp_nzs override (see
-    ``partition.validate_warp_nzs_override``); ``None`` means the derived
-    Algorithm-1 table. It is a tuple so configs stay hashable cache keys.
-    """
+    """Static knobs that change the partition layout (part of the cache key):
+    the partition mode and Algorithm 1's two bounds, from which the derived
+    per-degree pattern table follows."""
 
     mode: str = "tpu"
     max_block_warps: int = 64
     max_warp_nzs: int = 4
-    max_rows_per_block: Optional[int] = None
-    warp_nzs_table: Optional[Tuple[int, ...]] = None
-
-    def __post_init__(self):
-        if self.warp_nzs_table is not None and \
-                not isinstance(self.warp_nzs_table, tuple):
-            object.__setattr__(self, "warp_nzs_table",
-                               tuple(int(v) for v in self.warp_nzs_table))
 
     @property
     def deg_bound(self) -> int:
@@ -116,10 +104,6 @@ class PartitionPlan:
     # hash in ``key`` still changes with every version: the version is the
     # lineage, the hash is the identity.
     version: int = 0
-    # dispatch hints attached by the autotuner at promotion (JSON-able:
-    # backend/grid_order/label). None until a tuned candidate wins; spills
-    # and reloads with the plan so tuned configs survive eviction.
-    tuned: Optional[Dict] = None
 
     @property
     def graph_hash(self) -> str:
@@ -149,9 +133,7 @@ def build_partition_plan(g: CSRGraph, cfg: PartitionConfig,
     g.validate()
     gs = degree_sort_csr(g)
     pats = get_partition_patterns(
-        cfg.max_block_warps, cfg.max_warp_nzs, mode=cfg.mode,
-        max_rows_per_block=cfg.max_rows_per_block,
-        warp_nzs_override=cfg.warp_nzs_table)
+        cfg.max_block_warps, cfg.max_warp_nzs, mode=cfg.mode)
     bp = block_level_partition(gs, pats)
     slabs_np = pack_slabs(gs, bp)
     slabs = {k: jnp.asarray(v) for k, v in slabs_np.items()
@@ -460,8 +442,6 @@ class PlanCache:
             "bp_n_rows": np.int64(bp.n_rows),
             "bp_nnz": np.int64(bp.nnz),
         }
-        if plan.tuned is not None:
-            payload["tuned_json"] = np.array(json.dumps(plan.tuned))
         tmp = path + ".tmp"
         try:
             with open(tmp, "wb") as f:
@@ -506,14 +486,10 @@ class PlanCache:
                     nnz_blk=z["bp_nnz_blk"],
                     is_split=z["bp_is_split"],
                     patterns=get_partition_patterns(
-                        cfg.max_block_warps, cfg.max_warp_nzs, mode=cfg.mode,
-                        max_rows_per_block=cfg.max_rows_per_block,
-                        warp_nzs_override=cfg.warp_nzs_table),
+                        cfg.max_block_warps, cfg.max_warp_nzs, mode=cfg.mode),
                     n_rows=int(z["bp_n_rows"]),
                     nnz=int(z["bp_nnz"]),
                 )
-                tuned = (json.loads(str(z["tuned_json"]))
-                         if "tuned_json" in z else None)
                 return PartitionPlan(
                     key=key,
                     n_rows=int(z["n_rows"]), n_cols=int(z["n_cols"]),
@@ -524,7 +500,6 @@ class PlanCache:
                     coo_val=jnp.asarray(z["coo_val"]),
                     # pre-versioning spills reload as version 0
                     version=int(z["version"]) if "version" in z else 0,
-                    tuned=tuned,
                 )
         except Exception:       # corrupt/partial/alien spill (BadZipFile,
             return None         # KeyError, OSError, ...): rebuild instead

@@ -209,13 +209,10 @@ def _spmm_kernel(colidx_ref, values_ref, rowloc_ref, x_ref, out_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_rows", "interpret", "f_tile", "grid_order"),
+    static_argnames=("n_rows", "interpret", "f_tile"),
 )
 def _spmm_block_slabs(colidx, values, rowloc, out_row, x, n_rows, *,
-                      f_tile, grid_order, interpret):
-    if grid_order not in ("block_major", "ft_major"):
-        raise ValueError(
-            f"grid_order must be block_major|ft_major, got {grid_order!r}")
+                      f_tile, interpret):
     B, C = colidx.shape
     R = out_row.shape[1]
     N, F = x.shape
@@ -230,25 +227,14 @@ def _spmm_block_slabs(colidx, values, rowloc, out_row, x, n_rows, *,
     nf = F_pad // f_tile
     H, tph = value_heads(values, nf)
 
-    if grid_order == "block_major":
-        grid = (B, nf)
-        block_ix = lambda b, j: (b, 0, 0)       # noqa: E731
-        head_ix = lambda b, j: (b * H + j // tph, 0, 0)  # noqa: E731
-        x_ix = lambda b, j: (0, j)              # noqa: E731
-        out_ix = lambda b, j: (b, 0, j)         # noqa: E731
-    else:  # ft_major: (feature-tile, block) — block axis innermost
-        grid = (nf, B)
-        block_ix = lambda j, b: (b, 0, 0)       # noqa: E731
-        head_ix = lambda j, b: (b * H + j // tph, 0, 0)  # noqa: E731
-        x_ix = lambda j, b: (0, j)              # noqa: E731
-        out_ix = lambda j, b: (b, 0, j)         # noqa: E731
     out_slabs = pl.pallas_call(
         functools.partial(_spmm_kernel, C=C, R=R),
-        grid=grid,
-        in_specs=slab_meta_specs(C, block_ix, head_ix) + [
-            pl.BlockSpec((N_pad, f_tile), x_ix),
+        grid=(B, nf),
+        in_specs=slab_meta_specs(C, lambda b, j: (b, 0, 0),
+                                 lambda b, j: (b * H + j // tph, 0, 0)) + [
+            pl.BlockSpec((N_pad, f_tile), lambda b, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, R, f_tile), out_ix),
+        out_specs=pl.BlockSpec((1, R, f_tile), lambda b, j: (b, 0, j)),
         out_shape=jax.ShapeDtypeStruct((B, R, F_pad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((C, f_tile), jnp.float32)],
         interpret=interpret,
@@ -266,22 +252,12 @@ def spmm_block_slabs(
     n_rows: int,
     *,
     f_tile: int = DEFAULT_F_TILE,
-    grid_order: str = "block_major",
 ) -> jax.Array:
     """Run the Accel-GCN SpMM kernel over packed slabs; returns [n_rows, F].
 
-    ``grid_order`` picks the iteration order of the 2D grid (the ROADMAP
-    grid-order experiment; every (block, feature-tile) pair runs exactly
-    once either way, so outputs are identical):
-
-    * ``"block_major"`` (default): grid ``(B, nf)`` — the feature-tile
-      axis is innermost, so one block's slab metadata stays put while its
-      feature tiles sweep (one slab fetch per block, nf X-tile switches).
-    * ``"ft_major"``: grid ``(nf, B)`` — the block axis is innermost, so
-      ONE X feature tile stays resident across the whole block sweep; the
-      per-step revisit cost moves to the (much smaller) slab metadata.
-      This is the order that should win on real hardware once the X tile
-      dominates the per-step DMA traffic.
+    The grid is ``(B, nf)``: the feature-tile axis is innermost, so one
+    block's slab metadata stays put while its feature tiles sweep (one slab
+    fetch per block, nf X-tile switches).
 
     Raises :class:`repro.kernels.router.VmemBudgetError` when the resident
     X tile would not fit the VMEM budget (N_pad > 4096 at f32 defaults);
@@ -289,8 +265,7 @@ def spmm_block_slabs(
     gather kernel — ``backend="auto"`` picks for you.
     """
     return _spmm_block_slabs(colidx, values, rowloc, out_row, x, n_rows,
-                             f_tile=f_tile, grid_order=grid_order,
-                             interpret=pallas_interpret())
+                             f_tile=f_tile, interpret=pallas_interpret())
 
 
 def _spmm_kernel_windowed(colidx_ref, values_ref, rowloc_ref, x_ref, out_ref,

@@ -191,7 +191,6 @@ def spmm_batched(
     backend: str = "pallas",
     pad_blocks_to: Optional[int] = None,
     return_decision: bool = False,
-    grid_order: str = "block_major",
     phases: Optional[Dict[str, float]] = None,
     heads: Optional[Sequence[HeadValues]] = None,
 ) -> List[jax.Array] | Tuple[List[jax.Array], Optional[RoutingDecision]]:
@@ -207,11 +206,6 @@ def spmm_batched(
     ``windowed`` / ``hbm`` force those variants; ``blocked`` is the portable
     jnp twin. With ``return_decision=True`` the routing record (or ``None``
     for ``blocked``) comes back alongside the outputs.
-
-    ``grid_order`` ("block_major" | "ft_major") selects the resident
-    kernel's grid iteration order (see
-    :func:`repro.kernels.spmm_accel.spmm_block_slabs`); dispatches that
-    route to the windowed/HBM kernels ignore it.
 
     ``heads`` (one :class:`HeadValues` per graph, all with the same H;
     each slab dict then carries ``nnz``) makes the dispatch multi-head:
@@ -280,10 +274,7 @@ def spmm_batched(
             # sum of n_cols: the quantity that overflows the resident tile
             decision = route_spmm(int(x_cat.shape[0]), F, int(merged["C"]),
                                   int(merged["R"]), force=force)
-            kernel_kwargs = ({"grid_order": grid_order}
-                             if decision.backend == "resident" else {})
-            out = _PALLAS_KERNELS[decision.backend](
-                *slabs, x_cat, n_out, **kernel_kwargs)
+            out = _PALLAS_KERNELS[decision.backend](*slabs, x_cat, n_out)
         outs = [out[int(out_off[i]):int(out_off[i + 1]), :f_list[i]]
                 for i in range(G)]
     return (outs, decision) if return_decision else outs

@@ -15,8 +15,10 @@ Serving architecture (scheduler -> flush -> route -> dispatch)::
                                   dispatches of <= max_graphs_per_batch
                                                                |
                                   _dispatch: merge slabs, bucket blocks,
-                                  route by VMEM footprint (auto: resident /
-                                  windowed / hbm), ONE fused pallas_call
+                                  launch on the engine's backend (auto:
+                                  route_spmm picks resident / windowed /
+                                  hbm from the merged shape), ONE fused
+                                  pallas_call
                                                                |
                                   un-permute rows, split feature columns,
                                   item.complete(out) resolves each Future
@@ -30,7 +32,11 @@ requests on recurring graphs coalesce into fused dispatches no matter who
 submitted them, and the (thread-safe) plan cache is read from the single
 flush thread.
 
-Tuning knobs:
+Every dispatch launches one way: the engine's ``backend`` goes to
+``spmm_batched``, which with ``auto`` routes the merged shape through
+``router.route_spmm``; the chosen kernel runs its one grid.
+
+Knobs:
 
 * ``max_batch_requests`` / ``max_wait_ms`` — scheduler flush triggers.
   ``max_wait_ms`` bounds the co-batching wait of a lone request; under
@@ -64,7 +70,7 @@ import logging
 import math
 import threading
 import time
-from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import Future
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -81,8 +87,6 @@ from ..core.plan_repair import EdgeDelta, delta_chain_hash, repair_plan
 from ..kernels.edge_softmax import ATTENTION, NONE, PLAIN, to_plan_order
 from ..kernels.router import RoutingDecision, pad_features
 from ..kernels.spmm_batched import HeadValues, bucket_blocks, spmm_batched
-from ..tuning.tuner import PlanTuner
-from ..tuning.search import TuningCandidate
 from .scheduler import BatchScheduler, ClassSpec, WorkItem
 
 __all__ = ["Attention", "GraphRequest", "GraphServeEngine"]
@@ -143,7 +147,6 @@ class GraphServeEngine:
         feature_bucket: bool = True,
         classes: Optional[Sequence[ClassSpec]] = None,
         repair_churn_threshold: float = 0.25,
-        tuner: Optional[PlanTuner] = None,
     ):
         self.config = config or PartitionConfig()
         self.cache = cache if cache is not None else PlanCache(cache_capacity)
@@ -220,26 +223,6 @@ class GraphServeEngine:
         self.mutation_edges = 0      # edge inserts+deletes applied
         self.plan_repairs = 0        # publishes served by incremental repair
         self.plan_rebuilds = 0       # publishes that fell back to full build
-        # --- online partition autotuning (shadow-measured rollout) -------
-        # The tuner only ever acts on COPIES of live work: a shadow
-        # duplicates one dispatch onto the candidate plan on a separate
-        # single worker thread AFTER the live futures resolved, so the
-        # serving path never waits on a candidate (reads never pay for
-        # candidates). At most one shadow is in flight per engine; when
-        # the worker is busy the opportunity is skipped, never queued.
-        self.tuner = tuner
-        self._shadow_pool: Optional[ThreadPoolExecutor] = None
-        self._shadow_lock = threading.Lock()
-        self._shadow_inflight = False
-        # tuned dispatch hints by graph id, re-attached to plans rebuilt
-        # from scratch after an eviction (the structure comes back via the
-        # config in the key; the backend/grid_order hints live here)
-        self._tuned_hints: Dict[str, Dict] = {}
-        self.shadow_dispatches = 0   # candidate measurements completed
-        self.shadow_skipped = 0      # opportunities dropped (worker busy)
-        self.shadow_failures = 0     # candidate build/dispatch raised
-        self.shadow_time_s = 0.0     # wall time spent in shadow dispatches
-        self.tuned_promotions = 0    # tuned configs published
 
     # ------------------------------------------------------------------ admin
     def register_graph(self, graph_id: str, g: CSRGraph,
@@ -247,19 +230,11 @@ class GraphServeEngine:
         """Register (and warm the plan for) a graph under ``graph_id``.
 
         Re-registering the same id with identical content is a no-op (cache
-        hit); different content replaces the binding. A same-content
-        re-register keeps a TUNED binding (the autotuner may have promoted
-        a non-default config for this graph — identical content must not
-        silently reset it to ``self.config``).
+        hit); different content replaces the binding.
         """
         if normalize:
             g = gcn_normalize(g)
         h = graph_content_hash(g)
-        with self._bind_lock:
-            prev_key = self._keys.get(graph_id)
-        if prev_key is not None and prev_key[0] == h and \
-                prev_key != (h, self.config):
-            return self.plan_for(graph_id)  # tuned binding, same content
         key = (h, self.config)
         plan = self.cache.get_by_key(
             key, lambda: build_partition_plan(g, self.config, graph_hash=h))
@@ -297,7 +272,7 @@ class GraphServeEngine:
         return graph_id
 
     def unregister_graph(self, graph_id: str) -> bool:
-        """Drop a graph's binding (id -> graph/key/version/tuned hints).
+        """Drop a graph's binding (id -> graph/key/version).
 
         The plan itself stays in the LRU cache until evicted — a later
         ``register_subgraph`` of the same content re-binds without a
@@ -311,7 +286,6 @@ class GraphServeEngine:
             self._graphs.pop(graph_id, None)
             self._keys.pop(graph_id, None)
             self._versions.pop(graph_id, None)
-            self._tuned_hints.pop(graph_id, None)
         return known
 
     def submit_gather(self, graph_id: str, x: jax.Array,
@@ -352,22 +326,14 @@ class GraphServeEngine:
     def plan_for(self, graph_id: str) -> PartitionPlan:
         """Resolve a registered graph's plan WITHOUT rehashing its arrays —
         the content hash was paid once at registration; a rebuild only
-        happens if the plan was LRU-evicted since. The rebuild uses the
-        config EMBEDDED IN THE KEY (not ``self.config``): after the tuner
-        promotes a non-default config, an evicted plan must rebuild with
-        its tuned structure. Tuned dispatch hints are re-attached from the
-        engine's hint map when the rebuild lost them."""
+        happens if the plan was LRU-evicted since, with the config embedded
+        in the key."""
         with self._bind_lock:   # key and graph must be the SAME version
             key = self._keys[graph_id]
             g = self._graphs[graph_id]
-        plan = self.cache.get_by_key(
+        return self.cache.get_by_key(
             key, lambda: build_partition_plan(
                 g, key[1], graph_hash=key[0]))
-        if plan.tuned is None:
-            hints = self._tuned_hints.get(graph_id)
-            if hints is not None and plan.key[1] == hints["config"]:
-                plan.tuned = hints["tuned"]
-        return plan
 
     def graph_version(self, graph_id: str) -> int:
         """Current published version of a registered graph's plan chain."""
@@ -377,10 +343,6 @@ class GraphServeEngine:
     def close(self) -> None:
         """Stop the background scheduler (drains anything still queued)."""
         self.scheduler.stop()
-        with self._shadow_lock:
-            pool, self._shadow_pool = self._shadow_pool, None
-        if pool is not None:
-            pool.shutdown(wait=True)
 
     # ------------------------------------------------------------------ serve
     def _registered(self, graph_id: str) -> CSRGraph:
@@ -702,13 +664,12 @@ class GraphServeEngine:
         pad_to = None
         if self.block_bucket:
             pad_to = bucket_blocks(b_total, self.block_bucket)
-        backend, grid_order = self._effective_launch(plans)
         slabs = [p.slabs if heads is None
                  else dict(p.slabs, nnz=p.partition.nnz_blk) for p in plans]
         outs, decision = spmm_batched(
             slabs, xs, [p.n_rows for p in plans],
-            backend=backend, pad_blocks_to=pad_to, return_decision=True,
-            grid_order=grid_order, phases=phases, heads=heads)
+            backend=self.backend, pad_blocks_to=pad_to,
+            return_decision=True, phases=phases, heads=heads)
         with spans.span(spans.WAIT, phases):
             jax.block_until_ready(outs)
         # host wall time of this dispatch up to ready outputs: the phases
@@ -767,10 +728,6 @@ class GraphServeEngine:
                 self.dispatch_phase_s[name] += seconds
         for item, result in answers:
             item.complete(result)
-        # autotuning LAST: every live answer above already resolved, so
-        # shadow work can never sit between a request and its result
-        if self.tuner is not None and heads is None:
-            self._tuner_tick(batch, xs, dt)
 
     @staticmethod
     def _attention_of(item: WorkItem) -> Optional[Attention]:
@@ -871,170 +828,11 @@ class GraphServeEngine:
         return lambda out: out.reshape(out.shape[0], H, fhp)[
             :, h0:h0 + a.heads, :a.f_head]
 
-    # ------------------------------------------------------------ autotuning
-    def _effective_launch(self, plans: List[PartitionPlan]
-                          ) -> Tuple[str, str]:
-        """Backend/grid_order for one fused dispatch: a plan's tuned hints
-        apply when every plan in the batch agrees on the effective pair
-        (trivially true for the single-graph dispatches that dominate hot
-        traffic); a mixed batch falls back to the engine defaults."""
-        pairs = {(((p.tuned or {}).get("backend")) or self.backend,
-                  ((p.tuned or {}).get("grid_order")) or "block_major")
-                 for p in plans}
-        if len(pairs) == 1:
-            return pairs.pop()
-        return self.backend, "block_major"
-
-    def _tuner_tick(self, batch, xs, dt: float) -> None:
-        """Per-dispatch tuner hook (runs AFTER the live futures resolved).
-
-        Feeds the rate tracker, asks the tuner whether any graph in this
-        batch is due a shadow measurement, and hands at most one shadow to
-        the single worker thread. Multihost engines skip shadowing —
-        promotion would re-key the plan under the directory's feet; only
-        single-host engines tune (the multihost follow-on needs a version
-        broadcast like mutate()'s).
-        """
-        for gid, grp, _ in batch:
-            self.tuner.observe(gid, len(grp))
-        if getattr(self, "directory", None) is not None:
-            return      # multihost: directory-owned keys don't tune yet
-        for (gid, _grp, plan), x in zip(batch, xs):
-            cand = self.tuner.next_shadow(gid, plan.config)
-            if cand is None:
-                continue
-            self._submit_shadow(gid, plan, cand, x)
-
-    def _submit_shadow(self, gid: str, plan_i: PartitionPlan,
-                       cand: TuningCandidate, x: jax.Array) -> None:
-        """Hand one shadow measurement to the worker; skip if it's busy
-        (shadows are opportunistic — never queued, never blocking)."""
-        with self._shadow_lock:
-            if self._shadow_inflight:
-                busy = True
-            else:
-                busy = False
-                self._shadow_inflight = True
-                if self._shadow_pool is None:
-                    self._shadow_pool = ThreadPoolExecutor(
-                        max_workers=1, thread_name_prefix="plan-shadow")
-                pool = self._shadow_pool
-        if busy:
-            with self._counters_lock:
-                self.shadow_skipped += 1
-            return
-        pool.submit(self._run_shadow, gid, plan_i, cand, x)
-
-    def _run_shadow(self, gid: str, plan_i: PartitionPlan,
-                    cand: TuningCandidate, x: jax.Array) -> None:
-        """Worker-thread body: build the candidate plan (single-flight via
-        the cache) and run a PAIRED A/B measurement — the incumbent and
-        candidate plans dispatch the SAME features back-to-back in this
-        thread (1 untimed candidate warmup to absorb compilation, then
-        timed runs in ABBA order with the per-side min scored). Pairing is
-        what makes the comparison robust: both sides see the same
-        background load, so scheduler/GIL contention cancels instead of
-        poisoning the candidate's numbers. A promotion signal publishes
-        the candidate through the version chain."""
-        t_start = time.perf_counter()
-        old_key = plan_i.key
-        try:
-            with self._bind_lock:
-                stale = self._keys.get(gid) != old_key
-                g = self._graphs.get(gid)
-            if stale or g is None:
-                return              # graph mutated/replaced since the tick
-            key = (old_key[0], cand.config)
-            plan_c = self.cache.get_by_key(
-                key, lambda: build_partition_plan(
-                    g, cand.config, graph_hash=old_key[0]))
-            hints_i = plan_i.tuned or {}
-            launches = {
-                "inc": (plan_i, hints_i.get("backend") or self.backend,
-                        hints_i.get("grid_order") or "block_major"),
-                "cand": (plan_c, cand.backend or self.backend,
-                         cand.grid_order),
-            }
-
-            def _once(which: str) -> float:
-                plan, backend, grid_order = launches[which]
-                pad_to = (bucket_blocks(plan.num_blocks, self.block_bucket)
-                          if self.block_bucket else None)
-                t0 = time.perf_counter()
-                jax.block_until_ready(spmm_batched(
-                    [plan.slabs], [x], [plan.n_rows],
-                    backend=backend, pad_blocks_to=pad_to,
-                    grid_order=grid_order))
-                return time.perf_counter() - t0
-
-            _once("cand")           # warmup: compilation must not score
-            # ABBA order de-phases background load: a live dispatch that
-            # overlaps the shadow window hits early and late slots alike,
-            # so neither side's min is systematically the contended one
-            # (short candidate runs otherwise phase-lock into the busy
-            # slots while long incumbent runs land in the idle gaps).
-            samples = [(w, _once(w)) for w in ("inc", "cand", "cand", "inc")]
-            incumbent_s = min(s for w, s in samples if w == "inc")
-            candidate_s = min(s for w, s in samples if w == "cand")
-            with self._counters_lock:
-                self.shadow_dispatches += 1
-            winner = self.tuner.record_shadow(gid, cand, incumbent_s,
-                                              candidate_s)
-            if winner is not None:
-                self._promote_tuned(gid, old_key, winner, plan_c)
-        except Exception:  # noqa: BLE001 — a broken candidate must not
-            logger.exception("shadow measurement failed for %r (%s)",
-                             gid, cand.label)        # take down the worker
-            with self._counters_lock:
-                self.shadow_failures += 1
-            self.tuner.candidate_failed(gid, cand)
-        finally:
-            with self._counters_lock:
-                self.shadow_time_s += time.perf_counter() - t_start
-            with self._shadow_lock:
-                self._shadow_inflight = False
-
-    def _promote_tuned(self, gid: str, old_key: tuple,
-                       cand: TuningCandidate, plan_c: PartitionPlan) -> None:
-        """Publish a winning candidate as the graph's next plan version.
-
-        Rides the same machinery as mutate(): under the mutation lock the
-        binding is re-checked (a racing mutation aborts the promotion —
-        the tuner forgets the graph and re-tunes if it stays hot), the
-        plan gets its tuned hints + the next chain version, and
-        ``_publish_version`` atomically publishes + re-binds. In-flight
-        reads keep their pinned incumbent version until they drain.
-        """
-        with self._mutate_lock:
-            with self._bind_lock:
-                if self._keys.get(gid) != old_key:
-                    aborted = True
-                else:
-                    aborted = False
-                    cur_ver = self._versions[gid]
-                    g = self._graphs[gid]
-            if aborted:
-                self.tuner.reset(gid)
-                return
-            plan_c.tuned = cand.tuned_hints()
-            plan_c.version = cur_ver + 1
-            self._publish_version(gid, g, plan_c, old_key)
-            self._tuned_hints[gid] = {"config": cand.config,
-                                      "tuned": dict(plan_c.tuned)}
-            with self._counters_lock:
-                self.tuned_promotions += 1
-        self.tuner.confirm_promoted(gid)
-        logger.info("promoted tuned config for %r: %s (version %d)",
-                    gid, cand.label, plan_c.version)
-
     # ------------------------------------------------------------------ stats
     def stats(self) -> Dict[str, float]:
         s = {f"cache_{k}": v for k, v in self.cache.stats().items()}
         s.update({f"sched_{k}": v
                   for k, v in self.scheduler.stats().items()})
-        if self.tuner is not None:
-            s.update({f"tuner_{k}": v
-                      for k, v in self.tuner.stats().items()})
         # engine counters are one atomic snapshot (same guarantee as
         # PlanCache.stats()); cache/scheduler snapshots above are each
         # internally consistent but taken a moment earlier
@@ -1087,13 +885,6 @@ class GraphServeEngine:
             mutation_edges=self.mutation_edges,
             plan_repairs=self.plan_repairs,
             plan_rebuilds=self.plan_rebuilds,
-            # online autotuning: shadow measurements + promotions
-            shadow_dispatches=self.shadow_dispatches,
-            shadow_skipped=self.shadow_skipped,
-            shadow_failures=self.shadow_failures,
-            shadow_time_s=self.shadow_time_s,
-            tuned_promotions=self.tuned_promotions,
-            tuned_graphs=len(self._tuned_hints),
         )
         return s
 

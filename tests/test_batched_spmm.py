@@ -9,7 +9,7 @@ from repro.kernels.ref import csr_spmm_ref
 from repro.kernels.spmm_accel import spmm_block_slabs
 from repro.kernels.spmm_batched import batch_graph_slabs, bucket_blocks, spmm_batched
 
-from conftest import make_powerlaw_csr, make_wide_csr
+from graph_helpers import make_powerlaw_csr, make_wide_csr
 
 
 def _plan_x(g, cfg, F, seed):
@@ -230,34 +230,3 @@ def test_batch_graph_slabs_sentinel_remap():
         assert live.min() >= out_off[i] and live.max() < out_off[i + 1]
         b0 += p.num_blocks
 
-
-def test_grid_order_ft_major_matches_block_major():
-    """ROADMAP grid-order experiment: iterating (feature-tile, block)
-    instead of (block, feature-tile) must be a pure schedule change —
-    identical outputs, including with multiple feature tiles (F > 128)."""
-    cfg = PartitionConfig()
-    gs = [gcn_normalize(make_powerlaw_csr(n=80 + 30 * i, seed=50 + i))
-          for i in range(3)]
-    plans = [build_partition_plan(g, cfg) for g in gs]
-    rng = np.random.default_rng(3)
-    xs = [jnp.asarray(rng.normal(size=(p.n_cols, 130 + i)), jnp.float32)
-          for i, p in enumerate(plans)]   # F > 128 -> 2 feature tiles
-    a = spmm_batched([p.slabs for p in plans], xs,
-                     [p.n_rows for p in plans], backend="pallas")
-    b = spmm_batched([p.slabs for p in plans], xs,
-                     [p.n_rows for p in plans], backend="pallas",
-                     grid_order="ft_major")
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                   atol=1e-5, rtol=1e-5)
-
-
-def test_grid_order_validated():
-    from repro.kernels.spmm_accel import spmm_block_slabs as kern
-    cfg = PartitionConfig()
-    p = build_partition_plan(gcn_normalize(make_powerlaw_csr(n=50, seed=1)),
-                             cfg)
-    x = jnp.ones((p.n_cols, 8), jnp.float32)
-    with pytest.raises(ValueError, match="grid_order"):
-        kern(p.slabs["colidx"], p.slabs["values"], p.slabs["rowloc"],
-             p.slabs["out_row"], x, p.n_rows, grid_order="diagonal")

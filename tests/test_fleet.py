@@ -33,7 +33,7 @@ from repro.launch.mesh import graph_mesh
 from repro.serve.fleet import FleetGraphEngine
 from repro.serve.graph_engine import GraphRequest, GraphServeEngine
 
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 
 def _plan(n=400, e=2600, seed=0):

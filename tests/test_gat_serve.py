@@ -18,7 +18,7 @@ from repro.kernels.ref import attention_spmm_ref, csr_spmm_ref
 from repro.serve.fleet import FleetGraphEngine
 from repro.serve.graph_engine import GraphServeEngine
 
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 ATOL = 2e-5
 BACKENDS = {"resident": "pallas", "windowed": "windowed", "hbm": "hbm",

@@ -6,7 +6,7 @@ from repro.core.graph import (
     CSRGraph, counting_sort_by_degree, csr_from_edges, csr_transpose,
     degree_sort_csr, degrees_from_rowptr, gcn_normalize,
 )
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 
 @settings(max_examples=30, deadline=None)

@@ -10,7 +10,7 @@ from repro.core.plan_cache import PlanCache
 from repro.core.spmm import make_accel_spmm
 from repro.serve.graph_engine import GraphRequest, GraphServeEngine
 
-from conftest import make_powerlaw_csr, make_wide_csr
+from graph_helpers import make_powerlaw_csr, make_wide_csr
 
 
 def _setup(n_graphs=3, backend="blocked", **ekw):

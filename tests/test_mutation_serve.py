@@ -25,7 +25,7 @@ from repro.core.graph import gcn_normalize
 from repro.core.plan_repair import EdgeDelta
 from repro.serve.graph_engine import GraphServeEngine
 
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 
 def _dense(g):

@@ -10,7 +10,7 @@ from repro.core.partition import (
     warp_level_partition,
 )
 
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 
 def _graph(n, seed, zipf=1.7):
@@ -181,7 +181,7 @@ def test_pack_slabs_every_nz_exactly_once():
 
 
 # ---------------------------------------------------------------------------
-# warp_nzs overrides (the autotuner's candidate axis): any ADMISSIBLE
+# warp_nzs overrides: any ADMISSIBLE
 # table yields bit-identical SpMM output on both kernel backends, and
 # inadmissible tables are rejected up front
 # ---------------------------------------------------------------------------

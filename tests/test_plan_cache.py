@@ -9,7 +9,7 @@ from repro.core.plan_cache import (
 from repro.core.spmm import make_accel_spmm
 from repro.models.gcn import GraphOp
 
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 
 def _g(seed, n=120):

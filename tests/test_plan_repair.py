@@ -18,7 +18,7 @@ from repro.core.plan_repair import (EdgeDelta, apply_and_repair,
                                     delta_chain_hash, repair_plan)
 from repro.kernels.ops import spmm_batched
 
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 BACKENDS = ["blocked", "pallas"]
 

@@ -17,7 +17,7 @@ from repro.data.graphs import seed_batches, seed_splits
 from repro.sampling import (
     Frontier, GraphStore, PartitionedStoreClient, sample_frontier,
 )
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 
 def _store(n=80, seed=0, normalize=False):

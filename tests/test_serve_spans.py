@@ -18,7 +18,7 @@ from repro.core.graph import gcn_normalize
 from repro.serve.graph_engine import GraphServeEngine
 from repro.serve.scheduler import BatchScheduler
 
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 HOLD_MS = 50.0   # long enough that the worker always holds a lone request
 ALL_SPANS = (spans.SCHED_HOLD, spans.DISPATCH, *spans.DISPATCH_PHASES,

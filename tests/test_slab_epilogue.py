@@ -27,7 +27,7 @@ from repro.kernels.spmm_batched import batch_graph_slabs, bucket_blocks
 from repro.kernels.spmm_hbm import spmm_block_slabs_hbm
 from repro.serve.graph_engine import GraphServeEngine
 
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 SMALL_CFG = PartitionConfig(max_block_warps=4, max_warp_nzs=2)  # deg_bound 8
 

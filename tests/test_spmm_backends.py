@@ -7,7 +7,7 @@ from repro.core.graph import gcn_normalize
 from repro.core.spmm import make_accel_spmm
 from repro.data.graphs import BENCHMARK_GRAPHS, make_power_law_graph
 from repro.kernels.ref import csr_spmm_ref
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 
 def test_all_backends_agree():

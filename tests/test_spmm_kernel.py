@@ -9,7 +9,7 @@ from repro.core.partition import (block_level_partition, get_partition_patterns,
                                   pack_slabs)
 from repro.kernels.ref import csr_spmm_ref
 from repro.kernels.spmm_accel import spmm_block_slabs
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 
 def _run(g, X, mode="tpu", mbw=32, mwn=8, kernel=None):

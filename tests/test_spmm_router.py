@@ -23,7 +23,7 @@ from repro.kernels.spmm_accel import (
     spmm_block_slabs_windowed,
 )
 
-from conftest import make_powerlaw_csr
+from graph_helpers import make_powerlaw_csr
 
 C, R = 256, 64
 WINDOW = resident_window_rows()          # 4096 at f32/128-lane defaults
